@@ -60,6 +60,9 @@ def run(quick: bool = False) -> List[dict]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env.pop("XLA_FLAGS", None)
+    # a rehearsal on 8 virtual CPU devices by design; it must never take
+    # (or wait for) an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]

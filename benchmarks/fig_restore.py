@@ -213,6 +213,9 @@ def run(quick: bool = False) -> List[dict]:
                      "throttle": THROTTLE_MBPS}
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # a rehearsal on 8 virtual CPU devices by design; it must never take
+    # (or wait for) an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=1800)
     if out.returncode != 0:

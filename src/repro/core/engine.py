@@ -33,7 +33,7 @@ from repro.analysis.locks import declares_lock, named_lock
 from repro.obs import trace as obs
 from repro.obs.metrics import metrics as obs_metrics
 
-from .host_cache import HostCache
+from .host_cache import HostCache, Reservation
 from .layout import FileWriter
 from .state_provider import (Chunk, CompositeStateProvider,
                              TensorStateProvider, DEFAULT_CHUNK_BYTES)
@@ -251,12 +251,12 @@ class DataMovementEngine:
                 f"host cache ({self.host_cache.capacity/2**20:.0f} MiB); "
                 f"raise host_cache_bytes — the cache must hold one full "
                 f"checkpoint version (paper §VI-C2)")
-        bound: List[TensorStateProvider] = []
+        reservations: List[Reservation] = []
         try:
-            for provider, _arr in capture_items:
-                provider.bind_reservation(
-                    self.host_cache.reserve(provider.nbytes))
-                bound.append(provider)
+            reservations = self.host_cache.reserve_many(
+                [p.nbytes for p, _ in capture_items])
+            for (provider, _arr), res in zip(capture_items, reservations):
+                provider.bind_reservation(res)
             # --- launch non-blocking D2H for every device shard (lazy
             # capture; overlaps with the next iteration's forward/backward,
             # §V-A2).
@@ -269,11 +269,8 @@ class DataMovementEngine:
             # Prologue failed mid-way: nothing was enqueued yet, so no lane
             # will ever drain these reservations — release them here or the
             # pinned pool leaks and the next save deadlocks in reserve().
-            for provider in bound:
-                try:
-                    provider.release()
-                except BaseException:
-                    pass
+            for res in reservations:
+                res.release()
             raise
         for plan in files:
             stats.n_files += 1

@@ -69,15 +69,25 @@ class HostCache:
         self.total_reserved = 0  # lifetime bytes, for stats
 
     # -- internals -----------------------------------------------------------
-    def _find_gap(self, nbytes: int) -> Optional[int]:
-        prev_end = 0
-        for start, end in self._allocated:
-            if start - prev_end >= nbytes:
-                return prev_end
-            prev_end = end
-        if self.capacity - prev_end >= nbytes:
-            return prev_end
-        return None
+    def _place(self, sizes: List[int]) -> Optional[List[int]]:
+        """First-fit start offsets for all of ``sizes`` at once, or None."""
+        taken = list(self._allocated)
+        starts: List[int] = []
+        for nbytes in sizes:
+            prev_end, start = 0, None
+            for s, e in taken:
+                if s - prev_end >= nbytes:
+                    start = prev_end
+                    break
+                prev_end = e
+            if start is None:
+                if self.capacity - prev_end < nbytes:
+                    return None
+                start = prev_end
+            starts.append(start)
+            taken.append((start, start + nbytes))
+            taken.sort()
+        return starts
 
     def _free(self, res: Reservation) -> None:
         with self._lock:
@@ -96,22 +106,34 @@ class HostCache:
     def reserve(self, nbytes: int, timeout: Optional[float] = None
                 ) -> Reservation:
         """Reserve ``nbytes``; blocks until space frees up (back-pressure)."""
-        nbytes = int(nbytes)
-        if nbytes > self.capacity:
+        return self.reserve_many([nbytes], timeout=timeout)[0]
+
+    def reserve_many(self, sizes: List[int], timeout: Optional[float] = None
+                     ) -> List[Reservation]:
+        """Reserve every one of ``sizes`` together, or wait until all fit.
+
+        All-or-nothing: a save whose buffers are half placed would hold
+        fragments of the cache that the rest of it can never fit between,
+        so two saves that each fit the cache alone would wait on each
+        other forever. Each reservation is still released on its own."""
+        sizes = [int(n) for n in sizes]
+        total = sum(sizes)
+        if total > self.capacity:
             raise CacheFullError(
-                f"request of {nbytes} B exceeds cache capacity {self.capacity} B")
+                f"request of {total} B exceeds cache capacity "
+                f"{self.capacity} B")
         t0 = time.perf_counter()
         with self._lock:
             while True:
-                start = self._find_gap(nbytes)
-                if start is not None:
+                starts = self._place(sizes)
+                if starts is not None:
                     break
                 if not self._freed.wait(timeout=timeout):
                     raise CacheFullError(
-                        f"timed out waiting for {nbytes} B of cache space")
-            self._allocated.append((start, start + nbytes))
+                        f"timed out waiting for {total} B of cache space")
+            self._allocated.extend((s, s + n) for s, n in zip(starts, sizes))
             self._allocated.sort()
-            self.total_reserved += nbytes
+            self.total_reserved += total
             used = sum(e - s for s, e in self._allocated)
             self.peak_usage = max(self.peak_usage, used)
         # Observability happens after the allocator lock is released (the
@@ -121,6 +143,6 @@ class HostCache:
         obs_metrics.set_gauge("host_cache.used_bytes", used)
         if obs.enabled():
             obs.add_span("host_cache.reserve", t0, t0 + waited,
-                         bytes=nbytes)
+                         bytes=total)
             obs.counter("host_cache.used_bytes", used)
-        return Reservation(start, nbytes, self)
+        return [Reservation(s, n, self) for s, n in zip(starts, sizes)]

@@ -31,11 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # optional: fall back to stdlib zlib when zstandard is not installed
-    import zstandard
-except ImportError:  # pragma: no cover - exercised on minimal installs
-    zstandard = None
 import zlib
+
+import zstandard
 
 from repro.kernels import ops as kops
 
@@ -53,20 +51,16 @@ class EncodedTensor:
 
 
 def _compress(b: bytes, level: int = 3) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=level).compress(b)
-    return zlib.compress(b, level)
+    return zstandard.ZstdCompressor(level=level).compress(b)
 
 
 _ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
 
 
 def _decompress(b: bytes) -> bytes:
-    if b[:4] == _ZSTD_MAGIC:  # sniff the frame so codecs mix across installs
-        if zstandard is None:
-            raise RuntimeError(
-                "payload was compressed with zstandard, which is not "
-                "installed; `pip install zstandard` to read it")
+    # Files written by installs without zstandard hold zlib frames: sniff
+    # the frame so they still read.
+    if b[:4] == _ZSTD_MAGIC:
         return zstandard.ZstdDecompressor().decompress(b)
     return zlib.decompress(b)
 

@@ -321,13 +321,27 @@ def xor_bytes(cur: np.ndarray, prev: np.ndarray) -> np.ndarray:
     """Bit-exact XOR of two equal-length byte arrays; returns a fresh
     uint8 array. Pallas delta kernel (``kernels/delta.py``) on TPU, NumPy
     on host — XOR has one right answer, so the paths are trivially
-    bit-identical (and the differential suite checks anyway)."""
+    bit-identical (and the differential suite checks anyway).
+
+    On TPU the bytes go through the kernel in zero-padded chunks of
+    ``DEFAULT_CHUNK_BYTES``: device memory stays bounded whatever the
+    tensor size (chain replay XORs whole tensors, from several restore
+    threads at once), and one chunk shape compiles once."""
     from repro.kernels import ops as kops  # deferred: jax import is heavy
+    cur = np.asarray(cur).reshape(-1).view(np.uint8)
+    prev = np.asarray(prev).reshape(-1).view(np.uint8)
     if kops.host_fastpath():
-        return np.bitwise_xor(np.asarray(cur).view(np.uint8),
-                              np.asarray(prev).view(np.uint8))
-    out = np.asarray(kops.delta_xor(cur, prev)).view(np.uint8)
-    return out[:cur.nbytes]
+        return np.bitwise_xor(cur, prev)
+    out = np.empty_like(cur)
+    step = DEFAULT_CHUNK_BYTES
+    for lo in range(0, cur.size, step):
+        hi = min(lo + step, cur.size)
+        a, b = cur[lo:hi], prev[lo:hi]
+        if hi - lo < step:
+            a = np.concatenate([a, np.zeros(step - (hi - lo), np.uint8)])
+            b = np.concatenate([b, np.zeros(step - (hi - lo), np.uint8)])
+        out[lo:hi] = np.asarray(kops.delta_xor(a, b)).view(np.uint8)[:hi - lo]
+    return out
 
 
 class DeltaStateProvider(TensorStateProvider):

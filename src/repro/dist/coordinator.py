@@ -497,8 +497,7 @@ class Coordinator:
                  ack_timeout_s: Optional[float] = None,
                  fault_hook: Optional[FaultHook] = None,
                  fault: Optional[ProcessFaultSpec] = None,
-                 start_method: str = "spawn",
-                 jax_distributed: bool = False):
+                 start_method: str = "spawn"):
         if world < 1:
             raise ValueError(f"world must be >= 1, got {world}")
         if runtime not in RUNTIME_KINDS:
@@ -543,8 +542,7 @@ class Coordinator:
                     checksum_files=checksum_files,
                     fault=fault if fault is not None
                     and fault.rank == r else None,
-                    on_dead=self._note_dead, start_method=start_method,
-                    jax_distributed=jax_distributed)
+                    on_dead=self._note_dead, start_method=start_method)
                 for r in range(world)]
 
     # ------------------------------------------------------- writer census

@@ -65,8 +65,7 @@ class ProcessRankRuntime(BaseRankRuntime):
                  checksum_files: bool = True,
                  fault: Optional[ProcessFaultSpec] = None,
                  on_dead: Optional[Callable[[int], None]] = None,
-                 start_method: str = "spawn",
-                 jax_distributed: bool = False):
+                 start_method: str = "spawn"):
         if mode not in RANK_ENGINES:
             raise ValueError(
                 f"coordinator ranks require a DataMovementEngine mode, "
@@ -91,7 +90,7 @@ class ProcessRankRuntime(BaseRankRuntime):
         self._proc = ctx.Process(
             target=worker_main,
             args=(child_conn, rank, world, mode, engine_kw,
-                  checksum_files, fault, jax_distributed),
+                  checksum_files, fault),
             daemon=True, name=f"dsllm-rankproc-{rank}")
         self._proc.start()
         child_conn.close()  # parent keeps exactly one end

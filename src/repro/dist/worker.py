@@ -53,18 +53,14 @@ def _fire_fault(fault: Any, point: str, rank: int, step: int,
 
 def worker_main(conn: Any, rank: int, world: int, mode: str,
                 engine_kw: Dict[str, Any], checksum_files: bool,
-                fault: Optional[Any] = None,
-                jax_distributed: bool = False) -> None:
+                fault: Optional[Any] = None) -> None:
     """Serve one rank's saves over ``conn`` until close/EOF."""
-    if jax_distributed:
-        # multi-host deployments initialize the jax collective runtime so
-        # device meshes span processes; the single-host simulation runs
-        # without it, and an unconfigured coordinator must not be fatal
-        try:
-            import jax
-            jax.distributed.initialize()
-        except Exception:
-            pass
+    # The parent holds the accelerator (a chip belongs to one process), and
+    # a rank only moves host bytes: pin JAX to the CPU before anything here
+    # asks for a backend. Env vars are too late — unpickling this function
+    # already imported JAX — but the platform config is read at first use.
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from repro.core.baselines import rank_file
     from repro.core.engine import CheckpointFuture
     from repro.dist.ipc import decode_record, encode_stats

@@ -15,8 +15,8 @@ checkpoints) plus the attention hot-spot of the model zoo:
   (XOR delta, int8 quantize) emits its payload *and* integrity digest in a
   single kernel invocation per chunk, reading the staged bytes exactly once.
 
-Each has a jit'd wrapper in :mod:`repro.kernels.ops` (with
-``interpret=True`` fallback on CPU) and a pure-NumPy/jnp oracle in
+Each has a jit'd wrapper in :mod:`repro.kernels.ops` (compiled on a TPU,
+interpreted elsewhere) and a pure-NumPy/jnp oracle in
 :mod:`repro.kernels.ref`; tests sweep shapes/dtypes against the oracle, and
 ``tests/test_fused_kernels.py`` proves the fused kernels bit-identical to
 the legacy multi-pass composition before the engine trusts either.

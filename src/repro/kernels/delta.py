@@ -28,7 +28,7 @@ def _xor_kernel(a_ref, b_ref, o_ref):
 
 
 def delta_xor(cur_u32: jax.Array, prev_u32: jax.Array, *,
-              block: int = BLOCK, interpret: bool = True) -> jax.Array:
+              interpret: bool, block: int = BLOCK) -> jax.Array:
     n = cur_u32.shape[0]
     assert n % block == 0 and cur_u32.shape == prev_u32.shape
     grid = (n // block,)
@@ -47,8 +47,8 @@ def _sub_kernel(a_ref, b_ref, o_ref):
     o_ref[...] = a_ref[...] - b_ref[...]
 
 
-def delta_f32(cur: jax.Array, prev: jax.Array, *, block: int = BLOCK,
-              interpret: bool = True) -> jax.Array:
+def delta_f32(cur: jax.Array, prev: jax.Array, *, interpret: bool,
+              block: int = BLOCK) -> jax.Array:
     n = cur.shape[0]
     assert n % block == 0 and cur.shape == prev.shape
     grid = (n // block,)

@@ -77,10 +77,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = (acc_scr[...] / (l_scr[...] + 1e-30)).astype(o_ref.dtype)
 
 
-def flash_attention_bh(q, k, v, *, kind: str = "full", window: int = 0,
-                       chunk: int = 0, q_block: int = Q_BLOCK,
-                       kv_block: int = KV_BLOCK,
-                       interpret: bool = True) -> jax.Array:
+def flash_attention_bh(q, k, v, *, interpret: bool, kind: str = "full",
+                       window: int = 0, chunk: int = 0,
+                       q_block: int = Q_BLOCK,
+                       kv_block: int = KV_BLOCK) -> jax.Array:
     """q: (BH, S, hd); k/v: (BH, T, hd) — batch and heads pre-folded."""
     BH, S, hd = q.shape
     T = k.shape[1]

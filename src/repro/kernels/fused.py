@@ -28,10 +28,11 @@ contribute zero, which makes block padding (and the zero-padded q rows of a
 partial tile) digest-neutral; padded *scale* rows are not in the payload and
 are masked out explicitly.
 
-Grid iterations on TPU are sequential, so the (1,1) digest accumulator in the
-output ref is race-free (same idiom as ``checksum.py``). VMEM budget per grid
-step: one 256 KiB u32 slab per input for the XOR kernels; a (256, 256) fp32
-tile + int8/scale outputs for the quantize kernels.
+Grid iterations on TPU are sequential, so the (1,1) SMEM digest accumulator
+in the output ref is race-free; it is int32 and reduced with wrapping, then
+bitcast to uint32 outside the kernel (same idiom as ``checksum.py``). VMEM
+budget per grid step: one 256 KiB u32 slab per input for the XOR kernels; a
+(256, 256) fp32 tile + int8/scale outputs for the quantize kernels.
 """
 
 from __future__ import annotations
@@ -42,7 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .checksum import BLOCK, WEIGHT_BASE, WEIGHT_MOD
+from .checksum import (BLOCK, WEIGHT_BASE, WEIGHT_MOD, accumulate_digest,
+                       digest_spec, digest_u32, wrapping_sum_i32)
 from .quantize import COLS, ROWS
 
 # int8q payload layout (see core/codecs.py): u32 n_rows | u32 raw_nbytes |
@@ -55,16 +57,6 @@ def _weights(idx_u32):
     return jnp.uint32(WEIGHT_BASE) + (idx_u32 % jnp.uint32(WEIGHT_MOD))
 
 
-def _accumulate(dig_ref, partial):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        dig_ref[0, 0] = jnp.uint32(0)
-
-    dig_ref[0, 0] = dig_ref[0, 0] + partial
-
-
 # --------------------------------------------------------------- delta route
 def _xor_checksum_kernel(a_ref, b_ref, o_ref, dig_ref):
     step = pl.program_id(0)
@@ -72,26 +64,27 @@ def _xor_checksum_kernel(a_ref, b_ref, o_ref, dig_ref):
     o_ref[...] = delta
     n = delta.shape[0]
     idx = jax.lax.iota(jnp.uint32, n) + jnp.uint32(step) * jnp.uint32(n)
-    _accumulate(dig_ref, jnp.sum(delta * _weights(idx), dtype=jnp.uint32))
+    accumulate_digest(dig_ref, wrapping_sum_i32(delta * _weights(idx)))
 
 
 def xor_checksum_u32(cur_u32: jax.Array, prev_u32: jax.Array, *,
-                     block: int = BLOCK, interpret: bool = True):
+                     interpret: bool, block: int = BLOCK):
     """(delta, digest-of-delta) in one read of ``cur``/``prev``."""
     n = cur_u32.shape[0]
     assert n % block == 0 and cur_u32.shape == prev_u32.shape
     grid = (n // block,)
-    return pl.pallas_call(
+    delta, dig = pl.pallas_call(
         _xor_checksum_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
                   pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   digest_spec()],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.uint32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.uint32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
     )(cur_u32, prev_u32)
+    return delta, digest_u32(dig)
 
 
 def _xor_fold_checksum_kernel(base_ref, d_ref, o_ref, dig_ref):
@@ -100,51 +93,53 @@ def _xor_fold_checksum_kernel(base_ref, d_ref, o_ref, dig_ref):
     o_ref[...] = jax.lax.bitwise_xor(base_ref[...], delta)
     n = delta.shape[0]
     idx = jax.lax.iota(jnp.uint32, n) + jnp.uint32(step) * jnp.uint32(n)
-    _accumulate(dig_ref, jnp.sum(delta * _weights(idx), dtype=jnp.uint32))
+    accumulate_digest(dig_ref, wrapping_sum_i32(delta * _weights(idx)))
 
 
 def xor_fold_checksum_u32(base_u32: jax.Array, delta_u32: jax.Array, *,
-                          block: int = BLOCK, interpret: bool = True):
+                          interpret: bool, block: int = BLOCK):
     """(base ^ delta, digest-of-delta): verify the payload while applying."""
     n = base_u32.shape[0]
     assert n % block == 0 and base_u32.shape == delta_u32.shape
     grid = (n // block,)
-    return pl.pallas_call(
+    folded, dig = pl.pallas_call(
         _xor_fold_checksum_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
                   pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   digest_spec()],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.uint32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.uint32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
     )(base_u32, delta_u32)
+    return folded, digest_u32(dig)
 
 
 # ------------------------------------------------------------ int8q route
 def _payload_digest_tile(q, scale, n_rows: int):
-    """Digest contribution of one (ROWS, COLS) tile's payload words.
+    """Digest contribution (int32 bits) of one (ROWS, COLS) tile's payload.
 
     q words: 4 consecutive int8 lanes pack little-endian into the u32 word
-    at payload index ``2 + n_rows + row * COLS//4 + word``; zero-padded rows
-    quantize to q == 0 and contribute nothing. Scale words sit at payload
-    index ``2 + row`` and exist only for live rows (padding is masked).
+    at payload index ``2 + n_rows + row * COLS//4 + col//4``. Since the
+    digest is linear mod 2^32, each byte is weighted in place as
+    ``(u8 << 8 * (col % 4)) * weight(word index)`` — no lane reshape.
+    Zero-padded rows quantize to q == 0 and contribute nothing. Scale words
+    sit at payload index ``2 + row`` and exist only for live rows (padding
+    is masked).
     """
     step = pl.program_id(0)
     rows, cols = q.shape
     words_per_row = cols // 4
     row0 = jnp.uint32(step) * jnp.uint32(rows)
-    qu = jax.lax.bitcast_convert_type(q, jnp.uint8).astype(jnp.uint32)
-    qw = qu.reshape(rows, words_per_row, 4)
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (rows, words_per_row, 4), 2)
-    words = jnp.sum(jnp.left_shift(qw, lane * jnp.uint32(8)), axis=-1,
-                    dtype=jnp.uint32)
-    r_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, words_per_row), 0)
-    c_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, words_per_row), 1)
+    qu = jax.lax.bitcast_convert_type(q.astype(jnp.int32) & 0xFF, jnp.uint32)
+    r_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
+    c_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
     q_idx = (jnp.uint32(PAYLOAD_HEADER_WORDS + n_rows)
-             + (row0 + r_iota) * jnp.uint32(words_per_row) + c_iota)
-    partial = jnp.sum(words * _weights(q_idx), dtype=jnp.uint32)
+             + (row0 + r_iota) * jnp.uint32(words_per_row)
+             + jnp.right_shift(c_iota, jnp.uint32(2)))
+    shift = jnp.left_shift(c_iota & jnp.uint32(3), jnp.uint32(3))
+    q_term = jnp.left_shift(qu, shift) * _weights(q_idx)
 
     sbits = jax.lax.bitcast_convert_type(scale, jnp.uint32)       # (rows, 1)
     s_rows = row0 + jax.lax.broadcasted_iota(jnp.uint32, (rows, 1), 0)
@@ -153,7 +148,7 @@ def _payload_digest_tile(q, scale, n_rows: int):
                        sbits * _weights(jnp.uint32(PAYLOAD_HEADER_WORDS)
                                         + s_rows),
                        jnp.uint32(0))
-    return partial + jnp.sum(s_term, dtype=jnp.uint32)
+    return wrapping_sum_i32(q_term) + wrapping_sum_i32(s_term)
 
 
 def _quant_checksum_kernel(x_ref, q_ref, s_ref, dig_ref, *, n_rows: int):
@@ -163,11 +158,11 @@ def _quant_checksum_kernel(x_ref, q_ref, s_ref, dig_ref, *, n_rows: int):
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     q_ref[...] = q
     s_ref[...] = scale
-    _accumulate(dig_ref, _payload_digest_tile(q, scale, n_rows))
+    accumulate_digest(dig_ref, _payload_digest_tile(q, scale, n_rows))
 
 
 def quantize_checksum_int8(x: jax.Array, n_rows: int, *,
-                           interpret: bool = True):
+                           interpret: bool):
     """x: (R, COLS) fp32, R % ROWS == 0 -> (q, scales, payload digest).
 
     ``n_rows`` is the live (un-padded) row count; the digest covers exactly
@@ -177,42 +172,44 @@ def quantize_checksum_int8(x: jax.Array, n_rows: int, *,
     assert R % ROWS == 0 and C == COLS and 0 < n_rows <= R
     grid = (R // ROWS,)
     kern = functools.partial(_quant_checksum_kernel, n_rows=n_rows)
-    return pl.pallas_call(
+    q, scales, dig = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec((ROWS, COLS), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((ROWS, COLS), lambda i: (i, 0)),
                    pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   digest_spec()],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.uint32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
     )(x)
+    return q, scales, digest_u32(dig)
 
 
 def _dequant_checksum_kernel(q_ref, s_ref, o_ref, dig_ref, *, n_rows: int):
     q = q_ref[...]
     scale = s_ref[...]
     o_ref[...] = q.astype(jnp.float32) * scale
-    _accumulate(dig_ref, _payload_digest_tile(q, scale, n_rows))
+    accumulate_digest(dig_ref, _payload_digest_tile(q, scale, n_rows))
 
 
 def dequantize_checksum_int8(q: jax.Array, scales: jax.Array, n_rows: int, *,
-                             interpret: bool = True):
+                             interpret: bool):
     """Symmetric decode: (fp32, payload digest) in one read of q/scales."""
     R, C = q.shape
     assert R % ROWS == 0 and C == COLS and 0 < n_rows <= R
     grid = (R // ROWS,)
     kern = functools.partial(_dequant_checksum_kernel, n_rows=n_rows)
-    return pl.pallas_call(
+    out, dig = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec((ROWS, COLS), lambda i: (i, 0)),
                   pl.BlockSpec((ROWS, 1), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((ROWS, COLS), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   digest_spec()],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.uint32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
     )(q, scales)
+    return out, digest_u32(dig)

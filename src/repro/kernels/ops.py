@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real TPU
-backends; the kernels themselves are written for the TPU target (BlockSpec
-VMEM tiling, MXU-shaped dots).
+This module alone decides ``interpret`` for the kernels (each kernel module
+requires it as a keyword): compiled on a TPU backend, interpreted elsewhere
+unless the caller passes it. The kernels themselves are written for the TPU
+target (BlockSpec VMEM tiling, SMEM digest scalars, MXU-shaped dots).
 """
 
 from __future__ import annotations
@@ -53,10 +54,29 @@ def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
 
 
 def as_u32(x) -> jax.Array:
-    """Flat uint32 view (zero-padding the byte tail)."""
-    b = jnp.asarray(x).reshape(-1).view(jnp.uint8)
-    b = _pad_to(b, 4)
-    return b.view(jnp.uint32)
+    """Flat little-endian uint32 words of any array's bytes (zero-padding
+    the byte tail).
+
+    4-byte dtypes bitcast in place. Narrower ones pack their elements into
+    words from strided 1-D slices: a bitcast through an (n, 4) or (n, 2)
+    shape would put that tiny minor dim on the TPU's 128-lane tiles and
+    grow the array 64-128x in HBM."""
+    x = jnp.asarray(x).reshape(-1)
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.uint8)
+    size = x.dtype.itemsize
+    if size == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    if size == 8:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+    per = 4 // size
+    u = _pad_to(jax.lax.bitcast_convert_type(
+        x, {1: jnp.uint8, 2: jnp.uint16}[size]), per)
+    words = u[0::per].astype(jnp.uint32)
+    for i in range(1, per):
+        words = words | jnp.left_shift(u[i::per].astype(jnp.uint32),
+                                       jnp.uint32(8 * size * i))
+    return words
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -111,8 +131,7 @@ def fused_xor_checksum(cur, prev, block: int = _delta.BLOCK,
     interp = _default_interpret() if interpret is None else interpret
     c = _pad_to(as_u32(cur), block)
     p = _pad_to(as_u32(prev), block)
-    delta, dig = _fused.xor_checksum_u32(c, p, block=block, interpret=interp)
-    return delta, dig[0, 0]
+    return _fused.xor_checksum_u32(c, p, block=block, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -122,18 +141,14 @@ def fused_xor_fold(base, delta, block: int = _delta.BLOCK,
     interp = _default_interpret() if interpret is None else interpret
     b = _pad_to(as_u32(base), block)
     d = _pad_to(as_u32(delta), block)
-    folded, dig = _fused.xor_fold_checksum_u32(b, d, block=block,
-                                               interpret=interp)
-    return folded, dig[0, 0]
+    return _fused.xor_fold_checksum_u32(b, d, block=block, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "interpret"))
 def fused_quantize_int8(x, n_rows: int, interpret: bool | None = None):
     """One-pass (q, scales, int8q payload digest); x: (R, 256) fp32."""
     interp = _default_interpret() if interpret is None else interpret
-    q, scales, dig = _fused.quantize_checksum_int8(x, n_rows,
-                                                   interpret=interp)
-    return q, scales, dig[0, 0]
+    return _fused.quantize_checksum_int8(x, n_rows, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "interpret"))
@@ -141,9 +156,8 @@ def fused_dequantize_int8(q, scales, n_rows: int,
                           interpret: bool | None = None):
     """One-pass (fp32, int8q payload digest): fused int8 decode + verify."""
     interp = _default_interpret() if interpret is None else interpret
-    out, dig = _fused.dequantize_checksum_int8(q, scales, n_rows,
-                                               interpret=interp)
-    return out, dig[0, 0]
+    return _fused.dequantize_checksum_int8(q, scales, n_rows,
+                                           interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -25,7 +25,7 @@ def _downcast_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].astype(jnp.bfloat16)
 
 
-def downcast_bf16(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def downcast_bf16(x: jax.Array, *, interpret: bool) -> jax.Array:
     """x: (R, C) fp32, R % ROWS == 0, C % COLS == 0 -> (R, C) bf16."""
     R, C = x.shape
     assert R % ROWS == 0 and C % COLS == 0
@@ -49,7 +49,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     s_ref[...] = scale
 
 
-def quantize_int8(x: jax.Array, *, interpret: bool = True):
+def quantize_int8(x: jax.Array, *, interpret: bool):
     """x: (R, C) fp32 -> (int8 (R, C), scales (R, 1) fp32), per-row symmetric."""
     R, C = x.shape
     assert R % ROWS == 0 and C % COLS == 0 and C == COLS, \
@@ -72,7 +72,7 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
 
 
 def dequantize_int8(q: jax.Array, scales: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     R, C = q.shape
     grid = (R // ROWS,)
     return pl.pallas_call(

@@ -14,16 +14,10 @@ import jax
 
 
 def make_mesh(dims: Sequence[int], axes: Sequence[str]):
-    """Version-compat ``jax.make_mesh``: only pass ``axis_types`` where it
-    exists (``jax.sharding.AxisType`` appeared after 0.4.x; on older JAX
-    the raw keyword raises ``AttributeError`` at call time)."""
-    dims = tuple(dims)
-    axes = tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(dims, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(dims, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler, as the partition rules assume)."""
+    return jax.make_mesh(tuple(dims), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

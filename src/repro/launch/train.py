@@ -41,7 +41,10 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config, smoke_variant
     from repro.core import CheckpointManager
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.training.loop import Trainer
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -26,38 +26,6 @@ def pytest_configure(config):
 def pytest_unconfigure(config):
     faulthandler.cancel_dump_traceback_later()
 
-# ---------------------------------------------------------------------------
-# `hypothesis` is an optional dev dependency (see requirements-dev.txt).
-# Property-based tests import `given`/`settings`/`strategies` from here: when
-# hypothesis is missing they collect fine and skip individually, while the
-# plain tests in the same modules keep running.
-try:
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - exercised on minimal installs
-    HAS_HYPOTHESIS = False
-
-    class _AnyStrategy:
-        """Stands in for `st.<anything>(...)` at decoration time."""
-
-        def __getattr__(self, name):
-            return lambda *a, **k: self
-
-        def __call__(self, *a, **k):
-            return self
-
-    st = _AnyStrategy()
-    HealthCheck = _AnyStrategy()
-
-    def given(*a, **k):
-        return lambda f: pytest.mark.skip(
-            reason="hypothesis not installed")(f)
-
-    def settings(*a, **k):
-        return lambda f: f
-
-
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

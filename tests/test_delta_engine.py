@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HealthCheck, given, settings, st
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.core import (CheckpointManager, DeltaPolicy, FileReader,
                         RestoreEngine, RestoreError)
@@ -60,8 +61,8 @@ def assert_bit_exact(restored, expected):
     for k, v in expected["model"].items():
         a = np.asarray(restored["model"][k])
         b = np.asarray(v)
-        np.testing.assert_array_equal(a.view(np.uint8).reshape(-1),
-                                      b.view(np.uint8).reshape(-1))
+        np.testing.assert_array_equal(a.reshape(-1).view(np.uint8),
+                                      b.reshape(-1).view(np.uint8))
 
 
 # ---------------------------------------------------------------- policy
@@ -283,11 +284,13 @@ def test_property_chain_replay_bit_exact(tmp_path_factory, seed, n_tensors,
 @pytest.mark.parametrize(
     "seed,n_tensors,keyframe_every,odd,n_saves",
     [(0, 3, 1, False, 2), (1, 2, 2, True, 4), (2, 4, 3, True, 6),
-     (3, 1, 3, False, 1)])
+     (3, 1, 3, False, 1),
+     # 0-d leaves: a float16 scalar (seed 1) and a float32 scalar (seed 3)
+     (1, 3, 2, True, 3), (3, 2, 3, False, 4)])
 def test_chain_replay_fixed_cases(tmp_path, seed, n_tensors, keyframe_every,
                                   odd, n_saves):
-    """The property above pinned to fixed cases, so minimal installs
-    (no hypothesis) keep the coverage."""
+    """The property above pinned to fixed cases, including draws that once
+    failed, so every run checks them."""
     _chain_roundtrip(tmp_path, seed, n_tensors, keyframe_every, odd, n_saves)
 
 

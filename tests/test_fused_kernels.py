@@ -16,8 +16,7 @@ payload. This module is that proof, plus the integration layers above it:
   once per chunk, before the encode allocates it, and every staged byte is
   read exactly once (``engine.bytes_encode_read``).
 
-Property tests ride hypothesis when it is installed; the parametrized
-fixed cases below are the fallback corpus and always run.
+Hypothesis property tests sit beside the parametrized fixed cases.
 """
 
 import os
@@ -42,12 +41,8 @@ from repro.obs.metrics import metrics as obs_metrics
 from repro.storage.file_format import StreamingFileChecksum
 from repro.storage.manifest import file_checksum
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # the container has no hypothesis — the
-    HAVE_HYPOTHESIS = False  # parametrized fixed cases are the corpus
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 
 def _bytes_case(nbytes: int, dtype, seed: int) -> np.ndarray:
@@ -457,23 +452,23 @@ def test_delta_budget_and_single_read_of_staged_bytes():
     np.testing.assert_array_equal(folded, cur)
 
 
-# ------------------------------------------------- property tests (bonus)
-if HAVE_HYPOTHESIS:
-    @given(st.integers(1, 65_536), st.integers(0, 2 ** 32 - 1))
-    @settings(max_examples=30, deadline=None)
-    def test_prop_delta_digest_is_payload_digest(nbytes, seed):
-        cur = _bytes_case(nbytes, np.uint8, seed=seed & 0xFFFF)
-        prev = _bytes_case(nbytes, np.uint8, seed=(seed >> 16) | 1)
-        delta, dig = encode_delta_chunk(cur, prev, with_digest=True)
-        assert dig == payload_digest(delta)
-        np.testing.assert_array_equal(np.bitwise_xor(prev, delta), cur)
+# ------------------------------------------------------- property tests
+@given(st.integers(1, 65_536), st.integers(0, 2 ** 32 - 1))
+@settings(max_examples=30, deadline=None)
+def test_prop_delta_digest_is_payload_digest(nbytes, seed):
+    cur = _bytes_case(nbytes, np.uint8, seed=seed & 0xFFFF)
+    prev = _bytes_case(nbytes, np.uint8, seed=(seed >> 16) | 1)
+    delta, dig = encode_delta_chunk(cur, prev, with_digest=True)
+    assert dig == payload_digest(delta)
+    np.testing.assert_array_equal(np.bitwise_xor(prev, delta), cur)
 
-    @given(st.integers(1, 32_768))
-    @settings(max_examples=20, deadline=None)
-    def test_prop_int8_payload_digest_roundtrip(nbytes):
-        raw = _bytes_case((-(-nbytes // 4)) * 4, np.float32,
-                          seed=nbytes)[:nbytes]
-        payload, dig = encode_int8_block(raw, with_digest=True)
-        assert dig == payload_digest(payload)
-        assert decode_int8_block(payload, 0, nbytes,
-                                 expect_digest=dig).nbytes == nbytes
+
+@given(st.integers(1, 32_768))
+@settings(max_examples=20, deadline=None)
+def test_prop_int8_payload_digest_roundtrip(nbytes):
+    raw = _bytes_case((-(-nbytes // 4)) * 4, np.float32,
+                      seed=nbytes)[:nbytes]
+    payload, dig = encode_int8_block(raw, with_digest=True)
+    assert dig == payload_digest(payload)
+    assert decode_int8_block(payload, 0, nbytes,
+                             expect_digest=dig).nbytes == nbytes

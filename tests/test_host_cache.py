@@ -5,7 +5,8 @@ import time
 
 import numpy as np
 import pytest
-from conftest import given, settings, st  # hypothesis, optional
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.host_cache import CacheFullError, HostCache
 
@@ -179,3 +180,25 @@ def test_property_intervals_never_overlap(ops):
         for (s1, e1), (s2, _e2) in zip(spans, spans[1:]):
             assert e1 <= s2
         assert all(0 <= s and e <= 2048 for s, e in spans)
+
+
+def test_reserve_many_places_all_or_nothing():
+    """A save's buffers are placed together or not at all. Placed one by
+    one, the second save below would take the 2-byte hole the first left,
+    and its 8-byte buffer would then never fit between the survivors."""
+    c = HostCache(10)
+    first = c.reserve_many([4, 2, 4])
+    first[1].release()
+    done = []
+    t = threading.Thread(target=lambda: done.append(c.reserve_many([2, 8])))
+    t.start()
+    time.sleep(0.1)
+    assert not done and c.used_bytes() == 8  # waits, holds nothing
+    first[0].release()
+    first[2].release()
+    t.join(timeout=5)
+    assert not t.is_alive() and done
+    assert [(r.start, r.nbytes) for r in done[0]] == [(0, 2), (2, 8)]
+    for r in done[0]:
+        r.release()
+    assert c.used_bytes() == 0

@@ -4,7 +4,8 @@ import os
 
 import numpy as np
 import pytest
-from conftest import given, settings, st  # hypothesis, optional
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.layout import (ALIGN, FileLayout, FileReader, FileWriter,
                                align_up)

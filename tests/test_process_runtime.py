@@ -95,6 +95,28 @@ class TestHealthyHierarchicalCommit:
             assert np.array_equal(restored["model"][k], v)
         mgr.close()
 
+    def test_rank_children_never_start_an_accelerator_backend(
+            self, tmp_path, monkeypatch):
+        """Children inherit the parent's env, and the parent may hold a
+        chip. A child told to use the TPU still runs its encode on the
+        CPU: the delta save below asks each child's JAX for its backend."""
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        mgr = _manager(str(tmp_path), _coordinator(),
+                       delta=DeltaPolicy(keyframe_every=4))
+        state = _state()
+        for step in (1, 2):
+            state = {"model": {k: v + np.float32(step)
+                               for k, v in state["model"].items()},
+                     "meta": state["meta"]}
+            mgr.save(step, state).wait_persisted()
+            mgr.wait_for_commit(step)
+        assert mgr.commit_errors == []
+        assert mgr.repository.manifest(2).meta["delta"]["keyframe"] is False
+        restored = mgr.restore(_restore_template(), step=2)
+        for k, v in state["model"].items():
+            assert np.array_equal(restored["model"][k], v)
+        mgr.close()
+
     def test_process_traces_merge_into_parent_export(self, tmp_path):
         mgr = _manager(str(tmp_path), _coordinator())
         with obs.tracing() as t:

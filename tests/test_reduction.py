@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HealthCheck, given, settings, st
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.core.reduction import (DifferentialCheckpointer, decode_tensor,
                                   encode_tensor)
@@ -42,7 +43,7 @@ def test_quantized_encode_bounded_error():
     enc, _ = encode_tensor(x, quant="int8")
     assert enc.quant == "int8"
     out = decode_tensor(enc).astype(np.float32)
-    # reconstruct with scales (same codec the encoder used — zstd or zlib)
+    # reconstruct with scales (stored as a zstd frame like the payload)
     from repro.core.reduction import _decompress
     scales = np.frombuffer(_decompress(enc.scales),
                            np.float32).reshape(256, 1)
@@ -189,65 +190,59 @@ def test_differential_smaller_than_full_for_slow_state(tmp_path):
     assert i1["compressed_bytes"] < i0["compressed_bytes"] / 50
 
 
-# ------------------------------------------------- zstd→zlib fallback path
-# (PR-1 made `zstandard` optional; these tests keep that path honest by
-# roundtripping both codecs and cross-decoding via zstd-frame sniffing.)
+# ------------------------------------------------- zstd writer, zlib reader
+# Payloads are always written as zstd frames. Checkpoints written by older
+# installs without zstandard hold zlib frames, which must still read back.
+
+import zlib
+
+import zstandard
 
 from repro.core import reduction as R
 
 
-def test_zlib_fallback_roundtrip(monkeypatch):
-    """With zstandard absent, encode/decode must roundtrip via zlib."""
-    monkeypatch.setattr(R, "zstandard", None)
+def test_zlib_fallback_roundtrip():
+    """A tensor whose payload was written by the former zlib fallback
+    decodes to the same bytes."""
     x = jax.random.normal(jax.random.PRNGKey(7), (333,), jnp.float32)
     enc, _work = encode_tensor(x)
+    enc.payload = zlib.compress(R._decompress(enc.payload), 3)
     assert enc.payload[:4] != R._ZSTD_MAGIC  # really a zlib frame
     np.testing.assert_array_equal(decode_tensor(enc), np.asarray(x))
 
 
-def test_zlib_payload_decodes_under_either_install(monkeypatch):
-    """A checkpoint written on a zlib-only box must read back on a box
-    with zstandard installed: _decompress sniffs the frame, it does not
-    trust the local default codec."""
-    monkeypatch.setattr(R, "zstandard", None)
-    payload = R._compress(b"cross-install bytes" * 100)
-    monkeypatch.undo()  # whatever this box actually has
+def test_zlib_payload_decodes_under_either_install():
+    """_decompress sniffs the frame; it does not trust the writer's codec."""
+    payload = zlib.compress(b"cross-install bytes" * 100, 3)
     assert R._decompress(payload) == b"cross-install bytes" * 100
 
 
-@pytest.mark.skipif(R.zstandard is None, reason="zstandard not installed")
-def test_zstd_payload_roundtrip_and_rejection_without_zstd(monkeypatch):
-    """zstd frames decode when the module is present and fail with an
-    actionable error (not silent corruption) when it is not."""
+def test_zstd_payload_roundtrip():
+    """Encoding writes zstd frames, and they decode bit-exactly."""
     x = jax.random.normal(jax.random.PRNGKey(8), (222,), jnp.float32)
     enc, _ = encode_tensor(x)
     assert enc.payload[:4] == R._ZSTD_MAGIC
     np.testing.assert_array_equal(decode_tensor(enc), np.asarray(x))
-    monkeypatch.setattr(R, "zstandard", None)
-    with pytest.raises(RuntimeError, match="zstandard"):
-        R._decompress(enc.payload)
 
 
-def test_zstd_frame_sniffing_rejects_with_clear_error(monkeypatch):
-    """Even on a zlib-only install, a zstd frame is *recognized* (magic
-    sniff) and refused with install guidance — never fed to zlib."""
-    monkeypatch.setattr(R, "zstandard", None)
-    fake_zstd_frame = R._ZSTD_MAGIC + b"\x00" * 32
-    with pytest.raises(RuntimeError, match="pip install zstandard"):
-        R._decompress(fake_zstd_frame)
+def test_truncated_zstd_frame_raises():
+    """A damaged zstd frame raises; it is never fed to zlib or returned."""
+    frame = R._compress(b"some checkpoint bytes" * 64)
+    with pytest.raises(zstandard.ZstdError):
+        R._decompress(frame[:len(frame) // 2])
 
 
 def test_differential_checkpointer_cross_codec_restore(tmp_path, monkeypatch):
-    """Saves written with the fallback codec restore identically — the
-    whole differential chain (keyframe ⊕ deltas) survives a codec switch
-    between save and restore."""
+    """Saves written with zlib frames restore identically — the whole
+    differential chain (keyframe ⊕ deltas) survives a codec switch between
+    save and restore."""
     tree0 = {"a": jnp.arange(512, dtype=jnp.float32)}
     tree1 = {"a": tree0["a"].at[::5].add(1.0)}
-    monkeypatch.setattr(R, "zstandard", None)  # write zlib
+    monkeypatch.setattr(R, "_compress", zlib.compress)  # an older writer
     ck = DifferentialCheckpointer(str(tmp_path), keyframe_every=4)
     ck.save(0, tree0)
     ck.save(1, tree1)
-    monkeypatch.undo()  # read with the real install (zstd if present)
+    monkeypatch.undo()
     ck2 = DifferentialCheckpointer(str(tmp_path))
     state = ck2.restore(1)
     np.testing.assert_array_equal(state["['a']"], np.asarray(tree1["a"]))

@@ -5,8 +5,9 @@ restore — ISSUE 3 satellite)."""
 
 import numpy as np
 import pytest
-from conftest import (HealthCheck, given, run_in_subprocess, settings,
-                      st)  # hypothesis optional
+from conftest import run_in_subprocess
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.core import ENGINES, CheckpointManager
 

@@ -101,3 +101,20 @@ def test_hierarchical_composition():
         inner, host_tsp("y", np.zeros(128, np.uint8))])
     assert {p.name for p in outer.tensor_providers} == {"x", "y"}
     assert {p.name for p in outer.object_providers} == {"io"}
+
+
+def test_xor_bytes_kernel_path_chunks_and_pads(monkeypatch):
+    """The TPU branch of ``xor_bytes`` (forced here, kernels interpreted):
+    a whole tensor goes through the kernel in fixed, zero-padded chunks
+    and comes back bit-identical to NumPy's XOR, tail included."""
+    from repro.core import state_provider as sp
+    from repro.kernels import ops as kops
+
+    monkeypatch.setattr(kops, "host_fastpath", lambda: False)
+    monkeypatch.setattr(sp, "DEFAULT_CHUNK_BYTES", 1 << 18)
+    rng = np.random.default_rng(5)
+    cur = rng.integers(0, 256, (1 << 18) * 2 + 5, dtype=np.uint8)
+    prev = rng.integers(0, 256, cur.size, dtype=np.uint8)
+    out = sp.xor_bytes(cur, prev)
+    assert out.dtype == np.uint8 and out.size == cur.size
+    np.testing.assert_array_equal(out, np.bitwise_xor(cur, prev))

@@ -137,3 +137,25 @@ def test_pipeline_shapes_for_modalities():
         if cfg.n_prefix_embeds:
             assert b["prefix_embeds"].shape == (2, cfg.n_prefix_embeds,
                                                 cfg.d_model)
+
+
+def test_compile_cache_dir_from_env_or_fixed_repo_path(monkeypatch):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR`` wins
+    and is left to JAX; otherwise one fixed directory in the repo root."""
+    import os
+
+    from repro.launch import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert compile_cache.enable_compile_cache() == "/placed/outside"
+        assert jax.config.jax_compilation_cache_dir == was  # JAX's to read
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path  # no pid/time
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
