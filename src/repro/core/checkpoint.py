@@ -44,7 +44,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.analysis.locks import declares_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 from repro.storage.backend import BackendError
 from repro.storage.repository import (CheckpointRepository, RetentionPolicy,
                                       Tier, committed_steps)
@@ -593,7 +592,6 @@ class CheckpointManager:
                     tc1 = time.perf_counter()
                     future.stats.commit_s = tc1 - tc0
                     future.stats.t_committed = tc1
-                    obs_metrics.observe("commit.latency_s", tc1 - tc0)
                     obs.add_span("commit", tc0, tc1, step=future.step,
                                  flow=obs.flow_id("save", future.step),
                                  flow_phase="end")

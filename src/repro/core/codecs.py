@@ -141,7 +141,9 @@ def encode_int8_block(raw: np.ndarray, with_digest: bool = False):
         else:
             q, scales = kref.quantize_int8_ref(_pad_rows(f32))
     else:
-        q, scales, area = kops.fused_quantize_int8(_pad_rows(f32), n_rows)
+        q, scales, area = kops.on_host(
+            kops.fused_quantize_int8, _pad_rows(f32), n_rows=n_rows,
+            nbytes=raw_nbytes)
         if with_digest:
             digest = (_header_digest(n_rows, raw_nbytes) + int(area)) \
                 & 0xFFFFFFFF
@@ -198,7 +200,8 @@ def decode_int8_block(payload: bytes, raw_lo: int, raw_hi: int,
         # dequantize kernel on any backend
         deq = qp.astype(np.float32)[:n_rows] * scales
     else:
-        deq, area = kops.fused_dequantize_int8(qp, sp, n_rows)
+        deq, area = kops.on_host(kops.fused_dequantize_int8, qp, sp,
+                                 n_rows=n_rows, nbytes=raw_nbytes)
         if expect_digest is not None:
             got = (_header_digest(n_rows, raw_nbytes) + int(area)) \
                 & 0xFFFFFFFF
@@ -207,7 +210,7 @@ def decode_int8_block(payload: bytes, raw_lo: int, raw_hi: int,
                     f"int8q payload digest mismatch: stored "
                     f"{expect_digest:#010x}, decoded {got:#010x} — "
                     f"corrupt chunk")
-        deq = np.asarray(deq)[:n_rows]
+        deq = deq[:n_rows]
     out = deq.astype(np.float32).reshape(-1).view(np.uint8)
     return np.array(out[:raw_nbytes])
 
@@ -244,8 +247,7 @@ def encode_delta_chunk(cur: np.ndarray, prev: np.ndarray,
         else:
             delta = np.bitwise_xor(_u32_words(cur), _u32_words(prev))
     else:
-        delta, dig = kops.fused_xor_checksum(cur, prev)
-        delta = np.asarray(delta)
+        delta, dig = kops.on_host(kops.fused_xor_checksum, cur, prev)
         if with_digest:
             digest = int(dig)
     return delta.view(np.uint8)[:nbytes], digest

@@ -31,7 +31,6 @@ import numpy as np
 
 from repro.analysis.locks import declares_lock, named_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 
 from .host_cache import HostCache, Reservation
 from .layout import FileWriter
@@ -334,6 +333,7 @@ class DataMovementEngine:
                 # np.asarray blocks until the async device->host copy of this
                 # shard has completed, then views/copies the host buffer.
                 src = np.asarray(arr).reshape(-1).view(np.uint8)
+                t_landed = time.perf_counter()
                 dst = provider.reservation.array(np.uint8, (provider.nbytes,))
                 n = provider.nbytes
                 step = self.chunk_bytes
@@ -345,10 +345,14 @@ class DataMovementEngine:
                 provider.notify_staged(n)
                 t1 = time.perf_counter()
                 future.stats.stage_s += t1 - t0
-                obs_metrics.inc("engine.bytes_staged", n)
-                obs.add_span("d2h.stage", t0, t1, tensor=provider.name,
-                             bytes=n, step=future.step,
-                             flow=obs.flow_id("save", future.step))
+                if obs.enabled():
+                    attrs = dict(tensor=provider.name, bytes=n,
+                                 step=future.step)
+                    obs.add_span("d2h.wait", t0, t_landed, **attrs)
+                    obs.add_span("d2h.copy", t_landed, t1, **attrs)
+                    obs.add_span("d2h.stage", t0, t1,
+                                 flow=obs.flow_id("save", future.step),
+                                 **attrs)
                 one_staged()
             except BaseException as exc:  # noqa: BLE001
                 future._set_error(exc)
@@ -528,8 +532,6 @@ class DataMovementEngine:
                 t1 = time.perf_counter()
                 fut = op.file_state.future
                 fut.stats.flush_s += t1 - t0
-                obs_metrics.inc(
-                    "engine.bytes_written." + (chunk.codec or "raw"), nb)
                 obs.add_span("flush", t0, t1, chunk=chunk.name, bytes=nb,
                              step=fut.step,
                              flow=obs.flow_id("save", fut.step))

@@ -138,11 +138,9 @@ class HostCache:
             self.peak_usage = max(self.peak_usage, used)
         # Observability happens after the allocator lock is released (the
         # obs locks rank above host_cache.alloc, but no reason to hold it).
-        waited = time.perf_counter() - t0
-        obs_metrics.observe("host_cache.reserve_wait_s", waited)
         obs_metrics.set_gauge("host_cache.used_bytes", used)
         if obs.enabled():
-            obs.add_span("host_cache.reserve", t0, t0 + waited,
+            obs.add_span("host_cache.reserve", t0, time.perf_counter(),
                          bytes=total)
             obs.counter("host_cache.used_bytes", used)
         return [Reservation(s, n, self) for s, n in zip(starts, sizes)]

@@ -29,7 +29,6 @@ import numpy as np
 
 from repro.analysis.locks import declares_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 
 MAGIC = b"DSLLMCK1"
 ALIGN = 4096
@@ -188,7 +187,6 @@ class FileWriter:
             off = self._append_cursor
             self._append_cursor += len(payload)
         self._pwrite(self._fd, payload, off)
-        obs_metrics.inc("writer.append_bytes", len(payload))
         entry = ObjectEntry(name=name, offset=off, nbytes=len(payload),
                             codec=codec)
         with self._append_lock:
@@ -221,7 +219,6 @@ class FileWriter:
             off = self._append_cursor
             self._append_cursor += len(payload)
         self._pwrite(self._fd, payload, off)
-        obs_metrics.inc("writer.append_bytes", len(payload))
         with self._append_lock:
             self._enc_chunks.setdefault(name, []).append(
                 (off, len(payload), int(raw_lo), int(raw_hi),

@@ -69,7 +69,7 @@ class RestoreStats:
     index_s: float = 0.0      # footer/manifest indexing
     plan_s: float = 0.0       # intersection planning
     read_s: float = 0.0       # parallel ranged-read fan-out (wall clock)
-    assemble_s: float = 0.0   # host buffers -> device arrays
+    assemble_s: float = 0.0   # host-to-device copies enqueued (not done)
     bytes_read: int = 0       # bytes actually fetched from storage
     n_ranges: int = 0         # ranged reads issued
     n_files: int = 0          # checkpoint files indexed
@@ -684,7 +684,13 @@ class RestoreEngine:
     def _assemble(self, run: _Run, treedef, assembled,
                   idx: RestoreIndex) -> Any:
         """Host buffers -> leaves; objects resolved from ``idx`` (for a
-        chain restore: the newest step's object log)."""
+        chain restore: the newest step's object log).
+
+        ``restore.assemble`` and ``RestoreStats.assemble_s`` time the
+        enqueue of the host-to-device copies, not their completion. Traced,
+        ``restore.h2d`` runs from the same start until the device leaves
+        are ready, with their ``bytes``; the caller's next step waits for
+        them anyway."""
         stats = run.stats
         t0 = time.perf_counter()
         out = []
@@ -709,6 +715,11 @@ class RestoreEngine:
         stats.assemble_s += t1 - t0
         obs.add_span("restore.assemble", t0, t1, flow=run.flow,
                      flow_phase="end")
+        if obs.enabled():
+            leaves = jax.block_until_ready(
+                [x for x in out if isinstance(x, jax.Array)])
+            obs.add_span("restore.h2d", t0, time.perf_counter(),
+                         bytes=sum(x.nbytes for x in leaves))
         return tree
 
     def restore(self, sdir: str, template: Any

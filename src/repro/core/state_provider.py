@@ -340,7 +340,8 @@ def xor_bytes(cur: np.ndarray, prev: np.ndarray) -> np.ndarray:
         if hi - lo < step:
             a = np.concatenate([a, np.zeros(step - (hi - lo), np.uint8)])
             b = np.concatenate([b, np.zeros(step - (hi - lo), np.uint8)])
-        out[lo:hi] = np.asarray(kops.delta_xor(a, b)).view(np.uint8)[:hi - lo]
+        out[lo:hi] = kops.on_host(kops.delta_xor, a, b,
+                                  nbytes=hi - lo).view(np.uint8)[:hi - lo]
     return out
 
 
@@ -454,7 +455,8 @@ class DeltaStateProvider(TensorStateProvider):
                             # exactly, and delta is already in cache — the
                             # fused pass above is the chunk's only read of
                             # cur
-                            np.bitwise_xor(base, delta, out=base)
+                            with obs.span("encode.delta.base", bytes=nb):
+                                np.bitwise_xor(base, delta, out=base)
                             obs_metrics.inc("engine.bytes_encode_read", nb)
                     except BaseException:
                         # the chunk will never reach a flush lane, so

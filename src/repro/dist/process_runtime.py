@@ -39,7 +39,6 @@ from typing import Any, Callable, Dict, List, Optional
 
 from repro.analysis.locks import declares_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 from repro.core.engine import CheckpointFuture
 
 from .ipc import (ProcessDied, ProcessFaultSpec, RemoteRankError,
@@ -213,7 +212,6 @@ class ProcessRankRuntime(BaseRankRuntime):
         t_ack = time.perf_counter()
         job.rank_acked(self.rank, fut)
         t_done = time.perf_counter()
-        obs_metrics.observe("barrier.wait_s", t_done - t_ack)
         obs.add_span("ack.barrier", t_ack, t_done, lane=self.lane,
                      step=job.step, rank=self.rank, flow=flow,
                      flow_phase="end")

@@ -30,7 +30,6 @@ from typing import Callable, Dict, Optional
 
 from repro.analysis.locks import declares_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 
 from repro.storage.backend import BackendError, MemoryBackend
 
@@ -73,7 +72,6 @@ class FleetCache:
             if key in self._lru:
                 self._lru.move_to_end(key)
             self.stats["hits"] += 1
-        obs_metrics.inc("fleet.cache_hits")
         return data
 
     def get_through(self, key: str, fetch: Callable[[], bytes]) -> bytes:
@@ -110,7 +108,6 @@ class FleetCache:
                 self._flights.pop(key, None)
                 self.stats["misses"] += 1
                 self.stats["remote_bytes"] += len(data)
-            obs_metrics.inc("fleet.remote_bytes", len(data))
             fl.event.set()
             return data
 

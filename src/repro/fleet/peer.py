@@ -39,7 +39,6 @@ import numpy as np
 
 from repro.analysis.locks import declares_lock
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 
 from repro.core.codecs import payload_digest
 from repro.core.restore import plan_ranged_slices
@@ -184,8 +183,6 @@ class PeerExchange:
                      remote_bytes=stats.remote_bytes,
                      peer_bytes=stats.peer_bytes,
                      slices=stats.n_slices)
-        obs_metrics.inc("fleet.remote_bytes", stats.remote_bytes)
-        obs_metrics.inc("fleet.peer_bytes", stats.peer_bytes)
         return data
 
     def _contribute(self, sess: _SwapSession,

@@ -9,11 +9,14 @@ target (BlockSpec VMEM tiling, SMEM digest scalars, MXU-shaped dots).
 from __future__ import annotations
 
 import functools
+import time
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import trace as obs
 
 from . import checksum as _checksum
 from . import delta as _delta
@@ -38,11 +41,40 @@ def host_fastpath() -> bool:
     return _default_interpret()
 
 
+def on_host(kernel, *args, nbytes: int | None = None, **static):
+    """``kernel(*args, **static)`` on host arrays, its outputs back as NumPy
+    arrays: the device round trip of every encode and verify path that runs
+    a kernel on bytes already on the host.
+
+    Traced, the round trip is three spans, each with ``bytes`` (``nbytes``,
+    else the first input's) and ``kernel`` (the jitted function's name):
+    ``kernel.h2d``, an explicit ``device_put`` of the inputs;
+    ``kernel.call``, closed once the device has finished; ``kernel.d2h``,
+    the outputs copied back. The waits for the first two only move: the
+    copy back waits for both. Device inputs key the jit cache apart from
+    NumPy ones, so a traced process traces each input shape once more
+    (the compiled program is reused)."""
+    if not obs.enabled():
+        return jax.tree.map(np.asarray, kernel(*args, **static))
+    attrs = {"kernel": kernel.__name__,
+             "bytes": args[0].nbytes if nbytes is None else nbytes}
+    t0 = time.perf_counter()
+    args = jax.block_until_ready(jax.device_put(args))
+    t1 = time.perf_counter()
+    out = jax.block_until_ready(kernel(*args, **static))
+    t2 = time.perf_counter()
+    out = jax.tree.map(np.asarray, out)
+    obs.add_span("kernel.h2d", t0, t1, **attrs)
+    obs.add_span("kernel.call", t1, t2, **attrs)
+    obs.add_span("kernel.d2h", t2, time.perf_counter(), **attrs)
+    return out
+
+
 def tensor_checksum_fast(x) -> int:
     """``tensor_checksum`` as a Python int, via the fastest bit-exact path."""
     if host_fastpath():
         return _ref.checksum_np_bytes(np.asarray(x))
-    return int(tensor_checksum(x))
+    return int(on_host(tensor_checksum, x))
 
 
 def _pad_to(x: jax.Array, multiple: int) -> jax.Array:

@@ -1,37 +1,22 @@
-"""Process-wide metrics registry + the unified per-operation report schema.
+"""Process-wide metrics registry: counters, gauges and histograms.
 
-The registry holds counters (monotonic totals: bytes staged / encoded /
-written per codec, GC reclaim), gauges (host-cache occupancy), and
-histograms (reservation wait, barrier wait, commit latency). Everything is
-updated under one declared lock — ``obs.metrics`` at rank 82, above every
-runtime lock — and ``snapshot()`` returns plain data, so recording is legal
-from any instrumented seam and never does I/O.
-
-:class:`SaveReport` / :class:`RestoreReport` put the engine's divergent
-stats objects (``CheckpointFuture.stats``, ``RestoreStats``,
-``CascadeEvent``) behind one dict schema::
-
-    {"kind": "save" | "restore" | "cascade",
-     "step": int | None,
-     "phases": {phase_name: seconds, ...},
-     "bytes": {name: int, ...},
-     "counts": {name: int, ...},
-     "extra": {...}}
-
-Benchmarks and the ``storage.cli stats`` subcommand consume this shape
-instead of reaching into each stats object's ad-hoc attributes.
+Everything is updated under one declared lock — ``obs.metrics`` at rank 82,
+above every runtime lock — and ``snapshot()`` returns plain data, so
+recording is legal from any instrumented seam and never does I/O. Two
+entries are written: the counter ``engine.bytes_encode_read``, the encoded
+routes' single read of the staged bytes (``tests/test_fused_kernels.py``,
+``benchmarks/fig_encode.py``), and the gauge ``host_cache.used_bytes``,
+which the host cache also records as a trace counter.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import threading
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 from repro.analysis.locks import declares_lock
 
-__all__ = ["MetricsRegistry", "SaveReport", "RestoreReport",
-           "cascade_report", "metrics"]
+__all__ = ["MetricsRegistry", "metrics"]
 
 _HIST_SAMPLE_CAP = 512  # bounded reservoir per histogram
 
@@ -110,91 +95,3 @@ class MetricsRegistry:
 
 #: The process-wide registry every instrumented seam records into.
 metrics = MetricsRegistry()
-
-
-def _clean(d: Dict[str, Any]) -> Dict[str, Any]:
-    return {k: v for k, v in d.items() if v is not None}
-
-
-@dataclasses.dataclass
-class SaveReport:
-    """One save, as the unified report schema (see module docstring)."""
-
-    step: Optional[int]
-    phases: Dict[str, float]
-    bytes: Dict[str, int]
-    counts: Dict[str, int]
-    extra: Dict[str, Any]
-    kind: str = "save"
-
-    @classmethod
-    def from_future(cls, future: Any) -> "SaveReport":
-        """Build from a :class:`~repro.core.engine.CheckpointFuture` (or the
-        coordinator's aggregate future) — any object with a
-        ``CheckpointStats``-shaped ``.stats``."""
-        st = future.stats
-        phases = {
-            "blocking_s": st.blocking_s,
-            "stage_s": st.stage_s,
-            "serialize_s": st.serialize_s,
-            "flush_s": st.flush_s,
-        }
-        if st.t_captured:
-            phases["capture_s"] = st.capture_latency_s
-        if st.t_persisted:
-            phases["persist_s"] = st.persist_latency_s
-        commit_s = getattr(st, "commit_s", 0.0)
-        if commit_s:
-            phases["commit_s"] = commit_s
-        return cls(
-            step=getattr(future, "step", None),
-            phases=phases,
-            bytes={"tensors": st.bytes_tensors, "objects": st.bytes_objects,
-                   "total": st.total_bytes},
-            counts={"files": st.n_files, "tensors": st.n_tensors},
-            extra=dict(st.extra),
-        )
-
-    def to_dict(self) -> Dict[str, Any]:
-        return {"kind": self.kind, "step": self.step,
-                "phases": _clean(self.phases), "bytes": dict(self.bytes),
-                "counts": dict(self.counts), "extra": dict(self.extra)}
-
-
-@dataclasses.dataclass
-class RestoreReport:
-    """One restore, as the unified report schema."""
-
-    step: Optional[int]
-    phases: Dict[str, float]
-    bytes: Dict[str, int]
-    counts: Dict[str, int]
-    extra: Dict[str, Any]
-    kind: str = "restore"
-
-    @classmethod
-    def from_stats(cls, stats: Any,
-                   step: Optional[int] = None) -> "RestoreReport":
-        """Build from a :class:`~repro.core.restore.RestoreStats`."""
-        return cls(
-            step=step,
-            phases={"index_s": stats.index_s, "plan_s": stats.plan_s,
-                    "read_s": stats.read_s, "assemble_s": stats.assemble_s},
-            bytes={"read": stats.bytes_read},
-            counts={"tensors": getattr(stats, "n_tensors", 0)},
-            extra={},
-        )
-
-    def to_dict(self) -> Dict[str, Any]:
-        return {"kind": self.kind, "step": self.step,
-                "phases": _clean(self.phases), "bytes": dict(self.bytes),
-                "counts": dict(self.counts), "extra": dict(self.extra)}
-
-
-def cascade_report(event: Any) -> Dict[str, Any]:
-    """A :class:`~repro.storage.repository.CascadeEvent` in the same
-    schema (``kind="cascade"``)."""
-    return {"kind": "cascade", "step": event.step,
-            "phases": {"upload_s": event.t_end - event.t_start},
-            "bytes": {"uploaded": event.nbytes},
-            "counts": {}, "extra": {"tier": event.tier}}

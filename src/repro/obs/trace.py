@@ -1,6 +1,6 @@
 """Thread-aware span tracing with Chrome trace-event (Perfetto) export.
 
-Design constraints (ISSUE 7):
+Design constraints:
 
 * **Off by default, near-free when off.** ``span(...)`` reads one module
   global; when no tracer is installed it returns a shared no-op context
@@ -26,6 +26,31 @@ Design constraints (ISSUE 7):
 * **Flows.** Cross-lane causality (capture→D2H→encode→flush→commit;
   restore index→plan→read→assemble) is linked with Chrome flow events
   (``ph: s/t/f``) keyed by :func:`flow_id`.
+
+Spans of one rank, by the thread that records them (a dash introduces
+the children that lie inside a span). A traced benchmark run emits the
+trainer's, staging, producer, flush, commit and restore spans; the
+multi-rank and fleet ones come only from those runtimes.
+
+* trainer (``MainThread``): ``train.iteration`` — ``train.batch`` (next
+  batch and its host-to-device copy), ``train.grad_dispatch``,
+  ``train.update_dispatch``, ``train.loss_sync`` (``float(loss)``);
+  ``ckpt.capture_barrier``; ``save.prologue`` — ``host_cache.reserve``;
+  ``ckpt.exit_drain``; the instant ``save.request``.
+* staging lane: ``d2h.stage`` — ``d2h.wait`` (the async device-to-host
+  copy landing), ``d2h.copy`` (the copy into the host-cache reservation).
+* producer lanes: ``produce.file``; ``encode.digest``; ``encode.int8`` and
+  ``encode.delta`` — ``kernel.h2d``, ``kernel.call``, ``kernel.d2h`` (a
+  kernel's round trip on host bytes, ``kernels.ops.on_host``) and, for a
+  delta, ``encode.delta.base`` (the host XOR that advances the base).
+* flush lanes: ``encode.compress``, ``flush``, ``file.finalize``.
+* commit: ``manifest.write``, ``commit``, ``gc``, ``cascade.upload``.
+* restore: ``restore.index``, ``restore.plan``, ``restore.read``,
+  ``restore.assemble`` (the host-to-device copies enqueued),
+  ``restore.h2d`` (until the leaves are ready on the device).
+* ranks: ``vote``, ``node.vote``, ``ack.barrier``, ``rank.capture_wait``,
+  ``rank.persist_wait``, ``rank.ship``; fleet: ``fleet.fetch``,
+  ``fleet.swap``.
 
 Usage::
 

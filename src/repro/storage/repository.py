@@ -38,7 +38,6 @@ import threading
 import time
 
 from repro.obs import trace as obs
-from repro.obs.metrics import metrics as obs_metrics
 import uuid
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -687,7 +686,6 @@ class CheckpointRepository:
             self.cascade_log.append(CascadeEvent(
                 step=step, tier=tier.name, nbytes=nbytes,
                 t_start=t0, t_end=t1))
-        obs_metrics.inc("repo.cascade_bytes", nbytes)
         obs.add_span("cascade.upload", t0, t1, step=step, tier=tier.name,
                      bytes=nbytes, flow=obs.flow_id("save", step))
 
@@ -938,7 +936,6 @@ class CheckpointRepository:
         if not dry_run:
             with self._lock:
                 self.gc_log.append(report)
-            obs_metrics.inc("repo.gc_bytes_freed", report.bytes_freed)
             obs.add_span("gc", t0, t1, bytes_freed=report.bytes_freed,
                          steps=len(report.deleted_steps))
         return report

@@ -151,8 +151,11 @@ class Trainer:
             t0 = time.perf_counter()
             batch = {k: jnp.asarray(v)
                      for k, v in self.pipeline.next_batch().items()}
+            t_batch = time.perf_counter()
+            obs.add_span("train.batch", t0, t_batch)
             # --- immutable window: forward + backward ---------------------
             grads, loss = self.grad_step(self.params, batch)
+            obs.add_span("train.grad_dispatch", t_batch, time.perf_counter())
             # --- capture barrier before the donating update ---------------
             stall = 0.0
             if ckpt_pending:
@@ -161,8 +164,10 @@ class Trainer:
                 obs.add_span("ckpt.capture_barrier", t_b, t_b + stall,
                              step=self.step)
                 ckpt_pending = False
+            t_u = time.perf_counter()
             self.params, self.opt_state = self.update_step(
                 self.params, self.opt_state, grads)
+            obs.add_span("train.update_dispatch", t_u, time.perf_counter())
             self.step += 1
             # --- checkpoint request (lazy: overlaps next fwd/bwd) ---------
             requested = False
@@ -173,8 +178,10 @@ class Trainer:
                 stall += time.perf_counter() - t_save  # blocking prologue
                 ckpt_pending = True
                 requested = True
+            t_l = time.perf_counter()
             loss_val = float(loss)
             t1 = time.perf_counter()
+            obs.add_span("train.loss_sync", t_l, t1)
             self.records.append(IterationRecord(
                 step=self.step, loss=loss_val, iter_s=t1 - t0,
                 ckpt_stall_s=stall, ckpt_requested=requested))

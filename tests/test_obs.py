@@ -1,6 +1,6 @@
 """ckpttrace suite: tracer semantics, Chrome-JSON schema, ring bounds,
 the <1%-when-disabled overhead budget, the multi-rank lane/commit
-ordering, and the metrics registry / SaveReport schema (ISSUE 7)."""
+ordering, and the metrics registry."""
 
 import json
 import threading
@@ -12,7 +12,7 @@ import pytest
 from repro.core import (CheckpointManager, CheckpointPolicy, DeltaPolicy,
                         DistPolicy, EnginePolicy, StoragePolicy)
 from repro.obs import trace
-from repro.obs.metrics import MetricsRegistry, SaveReport
+from repro.obs.metrics import MetricsRegistry
 
 
 @pytest.fixture(autouse=True)
@@ -127,18 +127,29 @@ def test_chrome_json_schema(tmp_path):
 
 
 # --------------------------------------------------------- overhead budget
-def test_disabled_overhead_below_one_percent_of_iteration():
-    """The ISSUE 7 budget: with tracing off, the instrumentation's cost at
-    the training loop's span density must stay under 1% of a real (tiny)
-    training iteration."""
+def test_disabled_overhead_below_one_percent_of_iteration(tmp_path):
+    """With tracing off, the instrumentation's cost at the density of a
+    save every step must stay under 1% of such an iteration of a real
+    (tiny) Trainer. The density is counted, not assumed: the events a
+    traced run of the same loop records per iteration, on every lane
+    (for this model's 85 device leaves, about 540)."""
     from repro.configs import get_config, smoke_variant
     from repro.training.loop import Trainer
 
     assert not trace.enabled()
-    tr = Trainer(smoke_variant(get_config("llama2-7b")), batch=2, seq_len=32)
-    tr.run(2)                      # warm the jit caches
-    tr.records.clear()
-    tr.run(4)
+    mgr = CheckpointManager.from_policy(str(tmp_path), None)
+    tr = Trainer(smoke_variant(get_config("llama2-7b")), batch=2, seq_len=32,
+                 manager=mgr)
+    try:
+        tr.run(2, ckpt_interval=1)     # warm the jit caches
+        with trace.tracing() as t:
+            tr.run(4, ckpt_interval=1)
+        per_iter = len(t.events()) / 4
+        tr.records.clear()
+        tr.run(6, ckpt_interval=1)
+    finally:
+        mgr.close()
+    assert per_iter > 8            # the loop's own spans, at least
     iter_s = sorted(r.iter_s for r in tr.records)[len(tr.records) // 2]
 
     n = 100_000
@@ -148,11 +159,10 @@ def test_disabled_overhead_below_one_percent_of_iteration():
             pass
         trace.add_span("y", 0.0, 1.0, step=1)
     per_call = (time.perf_counter() - t0) / (2 * n)
-    # generous density bound: the full save path records well under 40
-    # spans per iteration at ckpt_interval=1
-    assert 40 * per_call < 0.01 * iter_s, (
-        f"disabled tracing costs {per_call * 1e9:.0f} ns/call — "
-        f"{40 * per_call / iter_s:.2%} of a {iter_s * 1e3:.1f} ms iteration")
+    assert per_iter * per_call < 0.01 * iter_s, (
+        f"disabled tracing costs {per_call * 1e9:.0f} ns/call x "
+        f"{per_iter:.0f} events — {per_iter * per_call / iter_s:.2%} of a "
+        f"{iter_s * 1e3:.1f} ms iteration")
 
 
 # ----------------------------------------------------- multi-rank ordering
@@ -227,26 +237,6 @@ def test_metrics_registry_counters_gauges_histograms():
     m.reset()
     assert m.snapshot() == {"counters": {}, "gauges": {}, "histograms": {}}
     assert json.dumps(snap)  # snapshot is always JSON-serializable
-
-
-def test_save_report_unifies_future_stats(tmp_path):
-    state = {"model": {"w": np.arange(4096, dtype=np.float32)},
-             "meta": {"step": 0}}
-    mgr = CheckpointManager.from_policy(str(tmp_path), None)
-    try:
-        fut = mgr.save(1, state)
-        fut.wait_persisted()
-        mgr.wait_for_commit(1)
-        rep = SaveReport.from_future(fut)
-    finally:
-        mgr.close()
-    assert rep.step == 1 and rep.kind == "save"
-    assert rep.phases["blocking_s"] >= 0
-    assert rep.phases["persist_s"] > 0
-    assert rep.phases["commit_s"] > 0
-    d = rep.to_dict()
-    assert json.dumps(d)
-    assert d["kind"] == "save" and d["step"] == 1
 
 
 def test_save_spans_carry_flow_links(tmp_path):
