@@ -9,8 +9,8 @@ The rule taints every name bound to a ``reserve(...)`` result (or a
 ``.buf``/``.data``/``view()`` of one) and flags subscript stores or
 augmented assignments through tainted names. Sanctioned lanes — the
 capture path itself — are exempt: all of ``core/state_provider.py``
-(providers own the capture protocol) and ``_stage_worker`` in
-``core/engine.py`` (the D2H copy target).
+(providers own the capture protocol) and ``_copy_shard`` in
+``core/engine.py`` (the copy lane's writes into the reservation).
 """
 
 from __future__ import annotations
@@ -22,7 +22,7 @@ from .linter import Finding, Project, Rule, SourceModule, call_name, \
     dotted
 
 SANCTIONED_MODULES = ("core/state_provider.py",)
-SANCTIONED_FUNCTIONS = {"_stage_worker"}
+SANCTIONED_FUNCTIONS = {"_copy_shard"}
 _RESERVATION_ATTRS = ("buf", "data", "view", "memoryview")
 
 

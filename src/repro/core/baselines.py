@@ -320,7 +320,7 @@ class DataStatesEngine(BaseCheckpointEngine):
                 else:
                     tp = TensorStateProvider(rec.tensor_name, **kw)
                 # uniform encoded-provider wiring: defer encode work until
-                # the device is drained (the staging lane runs uncontended,
+                # the device is drained (the landing lane runs uncontended,
                 # so encoded saves add no capture latency over raw
                 # snapshots) and bound in-flight payload allocations.
                 if getattr(tp, "capture_gate", False) is None:

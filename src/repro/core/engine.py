@@ -3,10 +3,13 @@
 The engine consumes chunk streams from composable state providers and moves
 them across tiers using separate physical paths in parallel:
 
-* a **staging lane** (models the device→host DMA copy engine): drains a queue
-  of device-resident tensors into their pre-reserved pinned-cache slices,
-  chunk by chunk, notifying the provider so downstream flushing can begin
-  before a tensor has fully landed;
+* a **landing lane** (models the device→host DMA copy engine): waits, in
+  submission order, for each device shard's async copy to land on the host
+  and hands the landed host array to the copy lane;
+* a **copy lane**: copies each landed shard into its pre-reserved
+  pinned-cache slice, chunk by chunk, notifying the provider so downstream
+  flushing can begin before a tensor has fully been copied. It holds each
+  landed host array only until that shard's copy is done;
 * **producer lanes** (one per checkpoint file): iterate the composite
   provider's chunk stream — tensors first, then lazily-serialized objects —
   and enqueue write ops;
@@ -14,9 +17,12 @@ them across tiers using separate physical paths in parallel:
   ``os.pwrite`` workers, multiple files in flight, GIL-released.
 
 Completion is tracked per request as two phases (paper Fig 6(c,d)):
-``captured`` (all device state has left the device — safe to mutate, i.e. the
-optimizer update may run) and ``persisted`` (all files durable, footer
-written).
+``captured`` (every device shard has landed on the host — safe to mutate,
+i.e. the optimizer update may run, while the copy lane may still be copying
+landed bytes into the cache) and ``persisted`` (all files durable, footer
+written). A landed host array is out of the donating update's reach: on an
+accelerator it is a separate host copy; on the CPU backend it is a view of
+the device buffer, which PJRT then declines to donate.
 """
 
 from __future__ import annotations
@@ -48,14 +54,16 @@ class CheckpointStats:
     def __init__(self) -> None:
         self.t_request: float = 0.0         # save() entered
         self.blocking_s: float = 0.0        # time training was blocked in save()
-        self.t_captured: float = 0.0
+        self.t_captured: float = 0.0        # every device shard landed
         self.t_persisted: float = 0.0
         self.bytes_tensors: int = 0
         self.bytes_objects: int = 0
         self.n_files: int = 0
         self.n_tensors: int = 0
         self.serialize_s: float = 0.0       # object serialization time
-        self.stage_s: float = 0.0           # device->host staging time
+        # per shard: the wait for its D2H to land plus its copy into the
+        # host cache, summed over shards (the two lanes overlap)
+        self.stage_s: float = 0.0
         self.flush_s: float = 0.0           # cumulative pwrite time
         self.t_committed: float = 0.0       # catalog manifest durable
         self.commit_s: float = 0.0          # manifest build+write duration
@@ -63,6 +71,8 @@ class CheckpointStats:
 
     @property
     def capture_latency_s(self) -> float:
+        """save() entered until every device shard has landed on the host
+        (the copies into the host cache may still be running)."""
         return self.t_captured - self.t_request
 
     @property
@@ -210,20 +220,24 @@ class DataMovementEngine:
         self.label = label
         self._flush_q: "queue.Queue[Optional[_WriteOp]]" = queue.Queue()
         self._stage_q: "queue.Queue[Optional[Tuple]]" = queue.Queue()
+        self._copy_q: "queue.Queue[Optional[Tuple]]" = queue.Queue()
         self._producer_q: "queue.Queue[Optional[Tuple]]" = queue.Queue()
         self._shutdown = False
         self._flush_threads = [
             threading.Thread(target=self._flush_worker, daemon=True,
                              name=f"{label}-flush-{i}")
             for i in range(flush_threads)]
+        self._stage_lane = f"{label}-stage"
         self._stage_thread = threading.Thread(
-            target=self._stage_worker, daemon=True, name=f"{label}-stage")
+            target=self._stage_worker, daemon=True, name=self._stage_lane)
+        self._copy_thread = threading.Thread(
+            target=self._copy_worker, daemon=True, name=f"{label}-copy")
         self._producer_threads = [
             threading.Thread(target=self._producer_worker, daemon=True,
                              name=f"{label}-producer-{i}")
             for i in range(producer_threads)]
         for t in (*self._flush_threads, self._stage_thread,
-                  *self._producer_threads):
+                  self._copy_thread, *self._producer_threads):
             t.start()
 
     # ------------------------------------------------------------------ API
@@ -289,7 +303,7 @@ class DataMovementEngine:
 
         capture_pending = {"n": len(capture_items)}
 
-        def one_staged() -> None:
+        def one_landed() -> None:
             with lock:
                 capture_pending["n"] -= 1
                 done = capture_pending["n"] == 0
@@ -299,7 +313,7 @@ class DataMovementEngine:
         if not capture_items:
             future._set_captured()
         for provider, arr in capture_items:
-            self._stage_q.put((provider, arr, one_staged, future))
+            self._stage_q.put((provider, arr, one_landed, future))
         for plan in files:
             self._producer_q.put((plan, file_done, future))
         if not files:
@@ -308,6 +322,7 @@ class DataMovementEngine:
     def drain(self) -> None:
         """Wait for all queued work (tests/benchmarks)."""
         self._stage_q.join()
+        self._copy_q.join()
         self._producer_q.join()
         self._flush_q.join()
 
@@ -315,49 +330,97 @@ class DataMovementEngine:
         self._shutdown = True
         for _ in self._producer_threads:
             self._producer_q.put(None)
-        self._stage_q.put(None)
+        self._stage_q.put(None)  # the landing lane passes it to the copy lane
         for _ in self._flush_threads:
             self._flush_q.put(None)
 
     # ------------------------------------------------------------ workers
     def _stage_worker(self) -> None:
-        """The D2H lane: drains device shards into their cache reservations."""
+        """The landing lane: waits for each device shard's D2H in order and
+        hands the landed host array to the copy lane. ``captured`` fires
+        here, once every shard of the save has landed."""
         while True:
             item = self._stage_q.get()
             if item is None:
+                self._copy_q.put(None)  # behind every shard already handed on
                 self._stage_q.task_done()
                 return
-            provider, arr, one_staged, future = item
+            provider, arr, one_landed, future = item
             try:
                 t0 = time.perf_counter()
                 # np.asarray blocks until the async device->host copy of this
                 # shard has completed, then views/copies the host buffer.
                 src = np.asarray(arr).reshape(-1).view(np.uint8)
                 t_landed = time.perf_counter()
-                dst = provider.reservation.array(np.uint8, (provider.nbytes,))
-                n = provider.nbytes
-                step = self.chunk_bytes
-                for pos in range(0, n, step):
-                    end = min(pos + step, n)
-                    dst[pos:end] = src[pos:end]
-                    if provider.stream_intra_tensor:
-                        provider.notify_staged(end)  # flush the staged head
-                provider.notify_staged(n)
+                self._copy_q.put((provider, src, t0, t_landed, future))
+                one_landed()
+            except BaseException as exc:  # noqa: BLE001
+                self._fail_shard(provider, future, exc)
+            finally:
+                item = arr = src = None
+                self._stage_q.task_done()
+
+    def _copy_worker(self) -> None:
+        """The copy lane: copies each landed shard into its cache
+        reservation, which may run after ``captured`` has fired."""
+        while True:
+            item = self._copy_q.get()
+            if item is None:
+                self._copy_q.task_done()
+                return
+            provider, src, t0, t_landed, future = item
+            try:
+                if future._error is not None:
+                    # the save already failed: wake this tensor's readers
+                    provider.fail_staging(future._error)
+                    continue
+                t_copy = time.perf_counter()
+                after_capture = future.captured
+                self._copy_shard(provider, src)
                 t1 = time.perf_counter()
-                future.stats.stage_s += t1 - t0
+                future.stats.stage_s += (t_landed - t0) + (t1 - t_copy)
                 if obs.enabled():
-                    attrs = dict(tensor=provider.name, bytes=n,
+                    attrs = dict(tensor=provider.name, bytes=provider.nbytes,
                                  step=future.step)
-                    obs.add_span("d2h.wait", t0, t_landed, **attrs)
-                    obs.add_span("d2h.copy", t_landed, t1, **attrs)
-                    obs.add_span("d2h.stage", t0, t1,
+                    lane = self._stage_lane
+                    obs.add_span("d2h.wait", t0, t_landed, lane=lane, **attrs)
+                    obs.add_span("d2h.copy", t_copy, t1, lane=lane,
+                                 after_capture=after_capture, **attrs)
+                    obs.add_span("d2h.stage", t0, t1, lane=lane,
                                  flow=obs.flow_id("save", future.step),
                                  **attrs)
-                one_staged()
             except BaseException as exc:  # noqa: BLE001
-                future._set_error(exc)
+                self._fail_shard(provider, future, exc)
             finally:
-                self._stage_q.task_done()
+                # drop the landed host array now: it may be the only thing
+                # keeping that host copy alive
+                item = src = None
+                self._copy_q.task_done()
+
+    def _copy_shard(self, provider: TensorStateProvider,
+                    src: np.ndarray) -> None:
+        """Copy one landed shard into its reservation chunk by chunk,
+        reporting each copied head so its flush starts before the tail is
+        copied."""
+        dst = provider.reservation.array(np.uint8, (provider.nbytes,))
+        n = provider.nbytes
+        step = self.chunk_bytes
+        for pos in range(0, n, step):
+            end = min(pos + step, n)
+            dst[pos:end] = src[pos:end]
+            if provider.stream_intra_tensor:
+                provider.notify_staged(end)  # flush the staged head
+        provider.notify_staged(n)
+
+    @staticmethod
+    def _fail_shard(provider: TensorStateProvider, future: CheckpointFuture,
+                    exc: BaseException) -> None:
+        """Fail the save over one shard that will never reach the cache; the
+        waiting producer then fails its file, which releases the file's
+        reservations and removes the partial file."""
+        provider.fail_staging(exc)
+        if future._error is None:
+            future._set_error(exc)
 
     def _producer_worker(self) -> None:
         """Iterate one file's chunk stream and enqueue write ops."""

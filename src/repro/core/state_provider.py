@@ -212,9 +212,10 @@ class TensorStateProvider(StateProvider):
     """Zero-copy SP for a byte-addressable tensor (host or device resident).
 
     For device arrays, :meth:`bind_reservation` attaches the pinned-cache
-    reservation and :meth:`notify_staged` is called by the staging thread as
-    bytes land; :meth:`chunks` yields each chunk as soon as its bytes are
-    staged, enabling flush/staging overlap within a single large tensor.
+    reservation and :meth:`notify_staged` is called by the engine's copy
+    lane as bytes are copied in; :meth:`chunks` yields each chunk as soon as
+    its bytes are staged, enabling flush/staging overlap within a single
+    large tensor.
     """
 
     def __init__(self, name: str, *, dtype: str, shape: Tuple[int, ...],
@@ -239,6 +240,7 @@ class TensorStateProvider(StateProvider):
         # device-resident path
         self._reservation: Optional[Reservation] = None
         self._staged = 0
+        self._stage_error: Optional[BaseException] = None
         self._cond = threading.Condition()
         self._released = False
         # Set by the engine when the save runs with manifest checksums:
@@ -262,10 +264,27 @@ class TensorStateProvider(StateProvider):
         return self._reservation
 
     def notify_staged(self, nbytes_total: int) -> None:
-        """Staging thread reports cumulative bytes landed in the cache."""
+        """Copy lane reports cumulative bytes copied into the cache."""
         with self._cond:
             self._staged = nbytes_total
             self._cond.notify_all()
+
+    def fail_staging(self, exc: BaseException) -> None:
+        """The engine reports that the rest of this tensor's bytes will
+        never reach the cache: every waiting reader raises."""
+        with self._cond:
+            self._stage_error = exc
+            self._cond.notify_all()
+
+    def _wait_staged(self, end: int) -> None:
+        """Block until bytes ``[0, end)`` are in the cache."""
+        with self._cond:
+            while self._staged < end:
+                if self._stage_error is not None:
+                    raise RuntimeError(
+                        f"staging of {self.name} failed"
+                    ) from self._stage_error
+                self._cond.wait()
 
     def release(self) -> None:
         """Free the cache reservation once all chunks are flushed."""
@@ -296,10 +315,8 @@ class TensorStateProvider(StateProvider):
             end = min(pos + self.chunk_bytes, n)
             if self._host_array is None:
                 # Wait until staging has landed these bytes (partial-tensor
-                # overlap: flush the head while the tail is still in DMA).
-                with self._cond:
-                    while self._staged < end:
-                        self._cond.wait()
+                # overlap: flush the head while the tail is still copied).
+                self._wait_staged(end)
             yield Chunk(name=self.name, kind="tensor", data=view[pos:end],
                         offset=self.offset + pos if self.offset is not None else None,
                         raw_range=(pos, end), last=end >= n,
@@ -379,16 +396,16 @@ class DeltaStateProvider(TensorStateProvider):
         # and the next save may start streaming.
         self.on_stream_end: Optional[Callable[[], None]] = None
         # Set by the engine to the save's `captured` event: streaming (and
-        # with it every producer-lane memcpy/XOR) is deferred until the
-        # device is fully drained, so the D2H staging lane never contends
-        # with encode work for the GIL — capture latency (the metric that
-        # blocks training) stays identical to the raw path; the XOR +
-        # compress pipeline runs in the shadow of the next iteration.
-        # Applied to keyframe mode too, deliberately: the keyframe's
-        # snapshot-cache refresh is a producer-lane memcpy that measurably
-        # (~2×) inflated capture when overlapped with staging; trading
-        # async persist tail for zero training stall is the right side of
-        # that bargain.
+        # with it every producer-lane memcpy/XOR) is deferred until every
+        # device shard has landed on the host, so the D2H landing lane never
+        # contends with encode work for the GIL — capture latency (the
+        # metric that blocks training) stays identical to the raw path; the
+        # XOR + compress pipeline runs beside the copy lane, in the shadow
+        # of the next iteration. Applied to keyframe mode too,
+        # deliberately: the keyframe's snapshot-cache refresh is a
+        # producer-lane memcpy that measurably (~2×) inflated capture when
+        # overlapped with staging; trading async persist tail for zero
+        # training stall is the right side of that bargain.
         self.capture_gate: Optional[threading.Event] = None
         # Set by the engine: bounds in-flight freshly-allocated XOR
         # payload bytes between producer and flush lanes.
@@ -421,9 +438,7 @@ class DeltaStateProvider(TensorStateProvider):
             while pos < n:
                 end = min(pos + self.chunk_bytes, n)
                 if self._host_array is None:
-                    with self._cond:
-                        while self._staged < end:
-                            self._cond.wait()
+                    self._wait_staged(end)
                 cur = np.frombuffer(view[pos:end], dtype=np.uint8)
                 if self.keyframe:
                     # refresh the snapshot, stream the raw bytes; the
@@ -511,7 +526,7 @@ class QuantizedStateProvider(TensorStateProvider):
             self.chunk_bytes - self.chunk_bytes % INT8_ROW_BYTES)
         # same engine wiring as DeltaStateProvider: encode work (a Pallas
         # kernel call per chunk) is deferred behind the save's captured
-        # event so the D2H staging lane never contends with it, and fresh
+        # event so the D2H landing lane never contends with it, and fresh
         # payload allocations are bounded by the engine's encode budget.
         self.capture_gate: Optional[threading.Event] = None
         self.encode_budget: Optional[EncodeBudget] = None
@@ -531,9 +546,7 @@ class QuantizedStateProvider(TensorStateProvider):
         while pos < n:
             end = min(pos + self.chunk_bytes, n)
             if self._host_array is None:
-                with self._cond:
-                    while self._staged < end:
-                        self._cond.wait()
+                self._wait_staged(end)
             raw = np.frombuffer(view[pos:end], dtype=np.uint8)
             # the int8q payload size is known a priori, so the encoded
             # footprint is reserved *before* the encode allocates it —

@@ -37,8 +37,11 @@ multi-rank and fleet ones come only from those runtimes.
   ``train.update_dispatch``, ``train.loss_sync`` (``float(loss)``);
   ``ckpt.capture_barrier``; ``save.prologue`` — ``host_cache.reserve``;
   ``ckpt.exit_drain``; the instant ``save.request``.
-* staging lane: ``d2h.stage`` — ``d2h.wait`` (the async device-to-host
-  copy landing), ``d2h.copy`` (the copy into the host-cache reservation).
+* staging lane (the landing and copy threads, one lane): ``d2h.stage`` —
+  ``d2h.wait`` (the async device-to-host copy landing), ``d2h.copy`` (the
+  copy into the host-cache reservation; ``after_capture`` when it started
+  after the save's ``captured`` fired). Successive shards' ``d2h.stage``
+  spans overlap: a shard lands while the one before it is copied.
 * producer lanes: ``produce.file``; ``encode.digest``; ``encode.int8`` and
   ``encode.delta`` — ``kernel.h2d``, ``kernel.call``, ``kernel.d2h`` (a
   kernel's round trip on host bytes, ``kernels.ops.on_host``) and, for a

@@ -36,6 +36,14 @@ def parent_of(child, parents):
     return found[0]
 
 
+def stage_of(child, stages):
+    """The ``d2h.stage`` of the child's shard that holds it: a shard lands
+    while the one before it is copied, so successive stages overlap."""
+    shard = (child["args"]["tensor"], child["args"]["step"])
+    return parent_of(child, [s for s in stages if
+                             (s["args"]["tensor"], s["args"]["step"]) == shard])
+
+
 def tiny_trainer(directory):
     mgr = CheckpointManager.from_policy(str(directory), None)
     return Trainer(smoke_variant(get_config("llama2-7b")), batch=2,
@@ -57,16 +65,19 @@ def test_stage_and_iteration_children_nest_in_their_parents(tmp_path):
         children = named(spans, name)
         assert len(children) == len(stages)
         for c in children:
-            p = parent_of(c, stages)
+            p = stage_of(c, stages)
             assert {k: c["args"][k] for k in ("bytes", "tensor", "step")} \
                 == {k: p["args"][k] for k in ("bytes", "tensor", "step")}
-    # the wait for the transfer comes first, the copy fills the rest
-    copies = {id(parent_of(c, stages)): c for c in named(spans, "d2h.copy")}
+    # the wait for the transfer comes first; the copy, on its own lane,
+    # starts once the shard has landed and the previous copy is done
+    copies = {id(stage_of(c, stages)): c for c in named(spans, "d2h.copy")}
     for w in named(spans, "d2h.wait"):
-        p = parent_of(w, stages)
+        p = stage_of(w, stages)
         c = copies[id(p)]
-        assert w["t0"] == p["t0"] and w["t1"] == c["t0"] \
+        assert w["t0"] == p["t0"] and w["t1"] <= c["t0"] \
             and c["t1"] == p["t1"]
+    assert all(isinstance(c["args"]["after_capture"], bool)
+               for c in named(spans, "d2h.copy"))
 
     iterations = named(spans, "train.iteration")
     assert len(iterations) == 3
