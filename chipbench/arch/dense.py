@@ -2,10 +2,22 @@
 attention with rotary embeddings over a sliding window and a GELU MLP,
 with biases and LayerNorm (StarCoder2).
 
-Each architecture file gives the layer's parameters in the program's
-layout (``block_spec``), its float32 reference (``block``), the widths the
-program's ``ModelConfig`` has to show (``program_widths``) and the layer's
-forward FLOPs per token (``layer_forward_flops``).
+Each architecture file gives everything the benchmark knows of one
+architecture, so that a configuration is added by files alone:
+
+* ``layer_groups(cfg)``: the stack as ``((pattern, count), ...)`` in the
+  program's block names, the program's ``ModelConfig.layer_groups``;
+* ``block_spec(cfg, kind)``: one layer's parameters of that block kind, in
+  the program's layout, with ``ln1`` and ``ln2``;
+* ``block(cfg, kind, p, x, lowp) -> (x, aux)``: its float32 reference,
+  ``aux`` a float32 scalar (zero for a kind with no auxiliary loss);
+* ``final_norm_spec(cfg)`` and ``final_norm(cfg, p, x)``: the norm before
+  the head;
+* ``aux_weight(cfg)``: the weight of the summed ``aux`` in the loss;
+* ``program_widths(cfg)``: every field of the program's ``ModelConfig``,
+  past ``d_model``, ``vocab`` and ``n_layers``, that has to match;
+* ``forward_flops(cfg, seq_len)``: the whole stack's forward FLOPs per
+  token, the head left out.
 """
 
 import math
@@ -17,18 +29,40 @@ import numpy as np
 from chipbench.reference import BF16, F32, Leaf, einsum, layer_norm, ln_spec
 
 
+KIND = "window"
+
+
+def layer_groups(cfg):
+    return (((KIND,), cfg["num_hidden_layers"]),)
+
+
 def norm_epsilon(cfg):
     return cfg["norm_epsilon"]
+
+
+def final_norm_spec(cfg):
+    return ln_spec(cfg["hidden_size"])
+
+
+def final_norm(cfg, p, x):
+    return layer_norm(x, p, norm_epsilon(cfg))
+
+
+def aux_weight(cfg):
+    return 0.0
 
 
 def program_widths(cfg):
     return dict(n_heads=cfg["num_attention_heads"],
                 n_kv_heads=cfg["num_key_value_heads"],
-                hd=cfg["head_dim"], window=cfg["sliding_window"],
-                rope_theta=cfg["rope_theta"], use_bias=cfg["use_bias"])
+                hd=cfg["head_dim"], d_ff=cfg["intermediate_size"],
+                window=cfg["sliding_window"],
+                rope_theta=cfg["rope_theta"], use_bias=cfg["use_bias"],
+                norm="layernorm", act="gelu_mlp")
 
 
-def block_spec(cfg):
+def block_spec(cfg, kind):
+    assert kind == KIND, kind
     d = cfg["hidden_size"]
     f = cfg["intermediate_size"]
     H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
@@ -60,14 +94,22 @@ def avg_keys(seq_len, window):
     return full / seq_len
 
 
-def layer_forward_flops(cfg, seq_len):
+def attention_flops(cfg, seq_len, window):
+    """Projections and the causal QK^T and PV over ``window`` keys (0:
+    all), per token."""
     d = cfg["hidden_size"]
-    f = cfg["intermediate_size"]
     H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
-    macs = d * H * hd + 2 * d * KV * hd + H * hd * d + 2 * d * f
-    attn = 2 * H * hd * avg_keys(seq_len, cfg.get("sliding_window", 0))
-    return 2 * macs + 2 * attn
+    macs = d * H * hd + 2 * d * KV * hd + H * hd * d
+    return 2 * macs + 2 * 2 * H * hd * avg_keys(seq_len, window)
+
+
+def forward_flops(cfg, seq_len):
+    d = cfg["hidden_size"]
+    f = cfg["intermediate_size"]
+    layer = attention_flops(cfg, seq_len, cfg["sliding_window"]) \
+        + 2 * 2 * d * f
+    return cfg["num_hidden_layers"] * layer
 
 
 def rope(x, theta):
@@ -81,24 +123,28 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def block(cfg, p, x, lowp):
-    B, S, d = x.shape
+def _project(h, a, w, b, lowp):
+    y = einsum("bsd,de->bse", h, a[w], lowp)
+    return y + a[b] if b in a else y
+
+
+def attention(cfg, a, h, window, lowp):
+    """Causal grouped-query self-attention with rotary embeddings over
+    ``window`` keys (0: all), one head at a time, with the input
+    projections' biases where ``a`` has them. Returns the output
+    projection; its bias ``bo``, if any, is the caller's to add."""
+    B, S, d = h.shape
     H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
-    eps = norm_epsilon(cfg)
-    h = layer_norm(x, p["ln1"], eps)
-    a = p["attn"]
-    q = (einsum("bsd,de->bse", h, a["wq"], lowp) + a["bq"]).reshape(
-        B, S, H, hd)
-    k = (einsum("bsd,de->bse", h, a["wk"], lowp) + a["bk"]).reshape(
-        B, S, KV, hd)
-    v = (einsum("bsd,de->bse", h, a["wv"], lowp) + a["bv"]).reshape(
-        B, S, KV, hd)
+    q = _project(h, a, "wq", "bq", lowp).reshape(B, S, H, hd)
+    k = _project(h, a, "wk", "bk", lowp).reshape(B, S, KV, hd)
+    v = _project(h, a, "wv", "bv", lowp).reshape(B, S, KV, hd)
     q = rope(q, cfg["rope_theta"])
     k = rope(k, cfg["rope_theta"])
     i = np.arange(S)[:, None]
     j = np.arange(S)[None, :]
-    allowed = jnp.asarray((j <= i) & (j > i - cfg["sliding_window"]))
+    allowed = (j <= i) & (j > i - window) if window else j <= i
+    allowed = jnp.asarray(allowed)
     rep = H // KV
 
     @jax.checkpoint
@@ -113,9 +159,18 @@ def block(cfg, p, x, lowp):
 
     heads = jax.lax.map(one_head, jnp.arange(H))          # (H, B, S, hd)
     o = heads.transpose(1, 2, 0, 3).reshape(B, S, H * hd)
-    x = x + einsum("bse,ed->bsd", o, a["wo"], lowp) + a["bo"]
+    return einsum("bse,ed->bsd", o, a["wo"], lowp)
+
+
+def block(cfg, kind, p, x, lowp):
+    assert kind == KIND, kind
+    eps = norm_epsilon(cfg)
+    h = layer_norm(x, p["ln1"], eps)
+    a = p["attn"]
+    x = x + attention(cfg, a, h, cfg["sliding_window"], lowp) + a["bo"]
     h2 = layer_norm(x, p["ln2"], eps)
     m = p["ffn"]
     up = einsum("bsd,df->bsf", h2, m["w_up"], lowp) + m["b_up"]
     act = jax.nn.gelu(up, approximate=True)
-    return x + einsum("bsf,fd->bsd", act, m["w_down"], lowp) + m["b_down"]
+    x = x + einsum("bsf,fd->bsd", act, m["w_down"], lowp) + m["b_down"]
+    return x, jnp.zeros((), F32)

@@ -11,18 +11,38 @@ from chipbench.reference import BF16, F32, Leaf, einsum, layer_norm, ln_spec
 
 MIX_LORA = 32      # the repository's time-mix LoRA width (Finch 7B: 32)
 TIME_CHUNK = 64    # steps between checkpoints of the reference recurrence
+KIND = "rwkv"
+
+
+def layer_groups(cfg):
+    return (((KIND,), cfg["num_hidden_layers"]),)
 
 
 def norm_epsilon(cfg):
     return cfg["layer_norm_epsilon"]
 
 
+def final_norm_spec(cfg):
+    return ln_spec(cfg["hidden_size"])
+
+
+def final_norm(cfg, p, x):
+    return layer_norm(x, p, norm_epsilon(cfg))
+
+
+def aux_weight(cfg):
+    return 0.0
+
+
 def program_widths(cfg):
-    return dict(rwkv_head_size=cfg["head_size"],
-                rwkv_decay_lora=cfg["time_decay_extra_dim"])
+    return dict(d_ff=cfg["intermediate_size"],
+                rwkv_head_size=cfg["head_size"],
+                rwkv_decay_lora=cfg["time_decay_extra_dim"],
+                norm="layernorm")
 
 
-def block_spec(cfg):
+def block_spec(cfg, kind):
+    assert kind == KIND, kind
     d = cfg["hidden_size"]
     f = cfg["intermediate_size"]
     hs = cfg["head_size"]
@@ -54,7 +74,7 @@ def block_spec(cfg):
     }
 
 
-def layer_forward_flops(cfg, seq_len):
+def forward_flops(cfg, seq_len):
     d = cfg["hidden_size"]
     f = cfg["intermediate_size"]
     hs = cfg["head_size"]
@@ -63,7 +83,7 @@ def layer_forward_flops(cfg, seq_len):
             + 2 * d * f + d * d)                        # channel mix
     # recurrence: state update, bonus and readout per state element
     wkv = (d // hs) * 3 * hs * hs
-    return 2 * macs + 2 * wkv
+    return cfg["num_hidden_layers"] * (2 * macs + 2 * wkv)
 
 
 def wkv6_stepwise(r, k, v, lw, u):
@@ -95,7 +115,8 @@ def _shift(x):
     return jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
 
 
-def block(cfg, p, x, lowp):
+def block(cfg, kind, p, x, lowp):
+    assert kind == KIND, kind
     B, T, d = x.shape
     hs = cfg["head_size"]
     H = d // hs
@@ -130,4 +151,5 @@ def block(cfg, p, x, lowp):
                                        c["w_in"], lowp)))
     rr = jax.nn.sigmoid(einsum("btd,de->bte", h2 + d2 * c["mix_r"],
                                c["w_r"], lowp))
-    return x + rr * einsum("btf,fd->btd", kk, c["w_out"], lowp)
+    x = x + rr * einsum("btf,fd->btd", kk, c["w_out"], lowp)
+    return x, jnp.zeros((), F32)

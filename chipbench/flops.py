@@ -16,9 +16,9 @@ XOR_BLOCK_WORDS = 65_536   # the fused XOR kernel's tile: inputs pad to it
 
 
 def forward_flops_per_token(cfg: Dict[str, Any], seq_len: int) -> float:
-    """The layers' (from the architecture file) and the head's."""
-    layer = spec.arch(cfg).layer_forward_flops(cfg, seq_len)
-    return cfg["num_hidden_layers"] * layer \
+    """The whole layer stack's (from the architecture file) and the
+    head's."""
+    return spec.arch(cfg).forward_flops(cfg, seq_len) \
         + 2 * cfg["hidden_size"] * cfg["vocab_size"]
 
 

@@ -130,19 +130,24 @@ class CompileCounter:
 
 # --------------------------------------------------------------- the model
 def program_config(config: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file, checked
-    against the file's widths (the architecture file names its own)."""
-    from repro.configs import get_config, uniform_groups
+    """The program's ``ModelConfig`` for a configuration file, with the
+    architecture file's layer stack, checked against the file's sizes (the
+    architecture file names every width past these three)."""
+    from repro.configs import get_config
 
-    o = dict(config["repro_overrides"])
-    block = o.pop("block")
-    cfg = dataclasses.replace(get_config(config["repro_config"]), **o)
-    cfg = dataclasses.replace(
-        cfg, layer_groups=uniform_groups(block, cfg.n_layers))
-    want = {"d_model": config["hidden_size"], "d_ff":
-            config["intermediate_size"], "vocab": config["vocab_size"],
+    arch = spec.arch(config)
+    groups = arch.layer_groups(config)
+    cfg = dataclasses.replace(get_config(config["repro_config"]),
+                              **config["repro_overrides"],
+                              layer_groups=groups)
+    layers = sum(len(pattern) * count for pattern, count in groups)
+    if layers != config["num_hidden_layers"]:
+        raise SystemExit(f"chipbench: {config['arch']} stacks {layers} "
+                         f"layers, {config['name']} has "
+                         f"{config['num_hidden_layers']}")
+    want = {"d_model": config["hidden_size"], "vocab": config["vocab_size"],
             "n_layers": config["num_hidden_layers"]}
-    want.update(spec.arch(config).program_widths(config))
+    want.update(arch.program_widths(config))
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise SystemExit(f"chipbench: program config {config['repro_config']}"

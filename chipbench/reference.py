@@ -1,12 +1,14 @@
 """The benchmark's own weights and its plain reference of each architecture.
 
-Nothing here imports the program. ``param_spec`` lays out a configuration's
-parameters in the tree the program's ``Trainer`` holds (the benchmark checks
-the two layouts agree before it installs its weights); ``make_state`` draws
-them on the device from the seed in one jitted call. The reference is
-straightforward float32 ``jax.numpy`` at ``highest`` matmul precision;
-each architecture's layer is in ``chipbench/arch/<arch>.py``, found from
-the configuration's ``arch``. ``train_reference``
+Nothing here imports the program, and nothing here is particular to one
+architecture: the layer stack, each block kind's parameters and reference,
+the final norm and the weight of any auxiliary loss come from
+``chipbench/arch/<arch>.py``, found from the configuration's ``arch``.
+``param_spec`` lays out a configuration's parameters in the tree the
+program's ``Trainer`` holds (the benchmark checks the two layouts agree
+before it installs its weights); ``make_state`` draws them on the device
+from the seed in one jitted call. The reference is straightforward float32
+``jax.numpy`` at ``highest`` matmul precision. ``train_reference``
 follows the first training steps with AdamW and returns the numbers that the
 check compares: each step's loss, each leaf's norm of the first (clipped)
 gradient, and each leaf's norm of the parameters' change after the steps.
@@ -44,6 +46,10 @@ def ln_spec(d):
     return {"scale": Leaf((d,), F32, "ones"), "bias": Leaf((d,), F32, "zeros")}
 
 
+def rms_spec(d):
+    return {"scale": Leaf((d,), F32, "ones")}
+
+
 def _stack(tree, n):
     return jax.tree_util.tree_map(
         lambda l: Leaf((n,) + l.shape, l.dtype, l.init, l.scale), tree,
@@ -52,14 +58,20 @@ def _stack(tree, n):
 
 def param_spec(cfg: Dict[str, Any]) -> Dict[str, Any]:
     """Every parameter's shape, dtype and init, in the program's layout:
-    the layers' from the configuration's architecture file."""
+    ``groups`` is a tuple over the architecture's layer groups, each a
+    tuple over its pattern's positions, each position's block stacked over
+    the group's count; the blocks and ``ln_f`` from the architecture
+    file."""
     V = cfg["vocab_size"]
     d = cfg["hidden_size"]
-    block = spec.arch(cfg).block_spec(cfg)
+    arch = spec.arch(cfg)
+    groups = tuple(tuple(_stack(arch.block_spec(cfg, kind), count)
+                         for kind in pattern)
+                   for pattern, count in arch.layer_groups(cfg))
     return {"embed": {"embed": Leaf((V, d), BF16, "normal", 0.02),
                       "head": Leaf((d, V), BF16, "normal", 0.02)},
-            "ln_f": ln_spec(d),
-            "groups": ((_stack(block, cfg["num_hidden_layers"]),),)}
+            "ln_f": arch.final_norm_spec(cfg),
+            "groups": groups}
 
 
 def _is_leaf(x):
@@ -93,9 +105,11 @@ def init_params(spec, key):
 
 
 def _static(cfg) -> str:
-    """The configuration as a hashable static argument of a jitted call."""
+    """The configuration's numbers, names and lists (a layer pattern) as a
+    hashable static argument of a jitted call."""
     return json.dumps({k: v for k, v in cfg.items()
-                       if isinstance(v, (int, float, str))}, sort_keys=True)
+                       if isinstance(v, (int, float, str, list))},
+                      sort_keys=True)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -140,22 +154,33 @@ def layer_norm(x, p, eps):
     return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
 
 
+def rms_norm(x, p, eps):
+    return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps) * p["scale"]
+
+
 def reference_loss(cfg, params, tokens, lowp: bool = False):
-    """Mean next-token cross-entropy over a block of rows (B, S)."""
+    """Mean next-token cross-entropy over a block of rows (B, S), plus the
+    architecture's weight times the blocks' summed auxiliary losses. The
+    blocks run in the program's scan order: groups, then repetitions, then
+    pattern positions."""
     p = jax.tree_util.tree_map(lambda a: a.astype(F32), params)
     d = cfg["hidden_size"]
     x = p["embed"]["embed"][tokens] * math.sqrt(d)
     arch = spec.arch(cfg)
-    stacked = p["groups"][0][0]
-    for layer in range(cfg["num_hidden_layers"]):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], stacked)
-        x = jax.checkpoint(lambda xx, pp: arch.block(cfg, pp, xx, lowp))(
-            x, lp)
-    x = layer_norm(x, p["ln_f"], arch.norm_epsilon(cfg))
+    aux = jnp.zeros((), F32)
+    for (pattern, count), group in zip(arch.layer_groups(cfg), p["groups"]):
+        for rep in range(count):
+            for kind, stacked in zip(pattern, group):
+                lp = jax.tree_util.tree_map(lambda a: a[rep], stacked)
+                x, a = jax.checkpoint(
+                    lambda xx, pp, kind=kind: arch.block(cfg, kind, pp, xx,
+                                                         lowp))(x, lp)
+                aux = aux + a
+    x = arch.final_norm(cfg, p["ln_f"], x)
     logits = einsum("bsd,dv->bsv", x[:, :-1], p["embed"]["head"], lowp)
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=-1)[..., 0]
-    return (logz - gold).mean()
+    return (logz - gold).mean() + arch.aux_weight(cfg) * aux
 
 
 # ---------------------------------------------------------------- training

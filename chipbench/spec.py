@@ -6,8 +6,9 @@ architecture or per-layer metric sits in files of its own, found by name:
 * a cell's configuration is the ``file`` its ``configs`` entry names;
 * its traffic mix is the data file ``chipbench/traffic/<traffic>.json``,
   whose ``kind`` names the generator ``chipbench/traffic/<kind>.py``;
-* a configuration's ``arch`` names its reference block and shapes,
-  ``chipbench/arch/<arch>.py``;
+* a configuration's ``arch`` names its architecture file,
+  ``chipbench/arch/<arch>.py``: the layer stack, each block kind's
+  parameters and reference, the final norm, the loss terms and the FLOPs;
 * each per-layer metric's reader is ``chipbench/metrics/<metric>.py``;
 * each checkpoint route's warm-up, where it has one, is
   ``chipbench/warm/<provider>.py``.
@@ -63,8 +64,9 @@ def load_module(directory: str, name: str) -> ModuleType:
 
 
 def arch(config: Dict[str, Any]) -> ModuleType:
-    """The architecture module a configuration's ``arch`` names."""
-    return load_module(ARCH_DIR, config["arch"])
+    """The architecture module a configuration's ``arch`` names, in its
+    ``arch_dir`` where a ``Cell`` gave it one."""
+    return load_module(config.get("arch_dir", ARCH_DIR), config["arch"])
 
 
 class Cell:
@@ -72,7 +74,7 @@ class Cell:
 
     def __init__(self, bench: Dict[str, Any], workload: str,
                  traffic_dir: str = TRAFFIC_DIR,
-                 kinds_dir: str = TRAFFIC_DIR):
+                 kinds_dir: str = TRAFFIC_DIR, arch_dir: str = ARCH_DIR):
         by_name = {w["name"]: w for w in bench["workloads"]}
         if workload not in by_name:
             raise KeyError(f"no workload {workload!r}; have "
@@ -83,6 +85,8 @@ class Cell:
         entry = {c["name"]: c for c in bench["configs"]}[
             self.workload["config"]]
         self.config = load_json(os.path.join(ROOT, entry["file"]))
+        if arch_dir != ARCH_DIR:
+            self.config["arch_dir"] = arch_dir
         self.traffic = load_json(os.path.join(
             traffic_dir, self.workload["traffic"] + ".json"))
         self.kind = load_module(kinds_dir, self.traffic["kind"])

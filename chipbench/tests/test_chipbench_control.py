@@ -17,7 +17,8 @@ def test_clean_run_is_correct(workload, tmp_path):
     assert set(out["metrics"]) >= {"setup_s"}
 
 
-@pytest.mark.parametrize("workload", ["sc2-save-raw", "rwkv6-save-delta"])
+@pytest.mark.parametrize("workload", ["sc2-save-raw", "rwkv6-save-delta",
+                                      "moe-save-raw"])
 def test_control_reads_far_above_the_program(workload, tmp_path):
     seed = 2**31 + 99
     run = harness.Run(tiny.cell(workload), seed, 0.0, str(tmp_path))

@@ -34,6 +34,20 @@ def test_rwkv6_flops_per_token_by_hand():
     assert math.isclose(flops.forward_flops_per_token(cfg, 4096), fwd)
 
 
+def test_moe_flops_count_the_routed_experts_by_hand():
+    cfg = spec.load_json(os.path.join(spec.HERE, "tests", "data",
+                                      "tiny-moe.json"))
+    cfg["arch_dir"] = os.path.join(spec.HERE, "tests", "data", "arch")
+    d, f, V, S, E, K, W = 64, 128, 256, 32, 4, 2, 8
+    proj = 2 * (d * 4 * 16 + 2 * d * 2 * 16 + 4 * 16 * d)
+    full = proj + 2 * 2 * 4 * 16 * (S + 1) / 2
+    window = proj + 2 * 2 * 4 * 16 * (W * (W + 1) / 2 + (S - W) * W) / S
+    ffn = 2 * 3 * d * f
+    moe = 2 * d * E + K * ffn            # the router, and K experts of E
+    fwd = (full + ffn) + 2 * (window + moe + full + moe) + 2 * d * V
+    assert math.isclose(flops.forward_flops_per_token(cfg, S), fwd)
+
+
 def test_window_shorter_than_sequence():
     # each of 8 queries sees min(i + 1, 4) keys: 1+2+3+4*5 = 26
     dense = spec.load_module(spec.ARCH_DIR, "dense")

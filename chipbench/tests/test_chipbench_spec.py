@@ -99,9 +99,16 @@ def test_a_traffic_kind_is_added_by_a_file_and_an_entry(tmp_path):
 def test_each_configuration_finds_its_architecture_file(name):
     cfg = spec.load_json(os.path.join(spec.HERE, "configs", name + ".json"))
     arch = spec.arch(cfg)
-    assert arch.program_widths(cfg)
-    assert arch.layer_forward_flops(cfg, 4096) > 0
-    assert set(arch.block_spec(cfg)) >= {"ln1", "ln2"}
+    groups = arch.layer_groups(cfg)
+    assert sum(len(pattern) * count for pattern, count in groups) \
+        == cfg["num_hidden_layers"]
+    assert "d_ff" in arch.program_widths(cfg)
+    assert arch.forward_flops(cfg, 4096) > 0
+    assert arch.aux_weight(cfg) >= 0
+    assert arch.final_norm_spec(cfg)
+    for pattern, _count in groups:
+        for kind in pattern:
+            assert set(arch.block_spec(cfg, kind)) >= {"ln1", "ln2"}
 
 
 def save_path_context(saves):
